@@ -358,6 +358,8 @@ def bench_cell(num_clients, *, rounds, seed=0, error_feedback=False,
 
 
 def worker(args):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     results = [bench_cell(k, rounds=args.rounds, seed=args.seed,
                           error_feedback=args.ef, base_store=args.base_store,
                           faults=args.faults, wire_format=args.wire_format,

@@ -8,6 +8,7 @@ overrides the round count, ``EXAMPLES_SCALE`` the dataset scale.
 """
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (FedAsyncSSL, FedAvgSSL, FedS3AConfig, FedS3ATrainer,
                         LocalSSL)
 from repro.data import make_dataset
@@ -17,6 +18,7 @@ SCALE = float(os.environ.get("EXAMPLES_SCALE", "0.008"))
 
 
 def main():
+    enable_compile_cache()
     data = make_dataset("basic", scale=SCALE, seed=0)
     cfg = FedS3AConfig(rounds=ROUNDS)
 

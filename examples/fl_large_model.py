@@ -22,6 +22,7 @@ overrides the round count, ``EXAMPLES_LM_CLIENTS`` the fleet width,
 import argparse
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, load_all
 from repro.core import FedS3AConfig, FedS3ATrainer, TrafficModel
 from repro.data import make_lm_dataset
@@ -32,6 +33,7 @@ CHUNKS = int(os.environ.get("EXAMPLES_LM_CHUNKS", "6"))
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--rounds", type=int, default=ROUNDS)

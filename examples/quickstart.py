@@ -297,6 +297,7 @@ overrides the round count, ``EXAMPLES_SCALE`` the dataset scale.
 """
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FedS3AConfig, FedS3ATrainer
 from repro.data import make_dataset
 
@@ -305,6 +306,7 @@ SCALE = float(os.environ.get("EXAMPLES_SCALE", "0.008"))
 
 
 def main():
+    enable_compile_cache()
     print("building synthetic CIC-IDS-2017 (basic / non-IID scenario)...")
     data = make_dataset("basic", scale=SCALE, seed=0)
     for i, (c, e) in enumerate(zip(data["clients"], data["entropy"])):

@@ -11,12 +11,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import lm
 from repro.training.steps import make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="jamba-1.5-large-398b")
     ap.add_argument("--batch", type=int, default=4)

@@ -65,8 +65,8 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.feds3a_cnn import CONFIG as CNN_CONFIG
 from repro.core import aggregation as agg
@@ -498,8 +498,13 @@ class FedS3ATrainer:
             self._data_map = np.arange(self.M, dtype=np.int64) % rows
             self._data_row_bytes = int(xs[0].nbytes + valid[0].nbytes)
         else:
-            self._x_pad = jnp.asarray(xs)
-            self._valid_pad = jnp.asarray(valid)
+            # the sharded engine replicates the fleet's data stack across
+            # the clients mesh, so each device gathers its participant
+            # rows locally instead of every shard pulling them off device 0
+            rep = None if self.mesh is None else \
+                NamedSharding(self.mesh, _REP)
+            self._x_pad = jax.device_put(xs, rep)
+            self._valid_pad = jax.device_put(valid, rep)
 
     def _gather_data(self, ids):
         """Participants' padded data rows as device arrays. Resident: a
@@ -1468,7 +1473,7 @@ class FedS3ATrainer:
         fn = jax.jit(shard_map(
             shard_fn, mesh=mesh,
             in_specs=(RING_SPEC, RING_SLOT_SPEC, _ROW3, _ROW2, _ROW, _ROW2),
-            out_specs=_ROW2, check_rep=False))
+            out_specs=_ROW2, check_vma=False))
         self._stage1_jits["chunk_train"] = fn
         return fn
 
@@ -1656,7 +1661,7 @@ class FedS3ATrainer:
                                   _PI if with_residual else _REP)
             fn = jax.jit(shard_map(
                 shard_fn, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
             self._stage1_jits[key] = fn
             return fn
 
@@ -1681,7 +1686,7 @@ class FedS3ATrainer:
             shard_fn, mesh=mesh,
             in_specs=base_specs + (_ROW3, _ROW2, _ROW, _ROW2,
                                    _ROW2 if with_residual else _REP),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
         self._stage1_jits[key] = fn
         return fn
 
@@ -1752,7 +1757,7 @@ class FedS3ATrainer:
                     in_specs=(_REP, RING_SPEC, RING_SLOT_SPEC, pspecs,
                               _ROW, _REP, _REP),
                     out_specs=(_REP, _REP) + (_REP,) * n_payload,
-                    check_rep=False))
+                    check_vma=False))
                 self._stage2_jits["finalize"] = fn
                 return fn
 
@@ -1764,7 +1769,7 @@ class FedS3ATrainer:
             fn = jax.jit(shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(_REP, _ROW2, pspecs, _ROW, _REP, _ROW2),
-                out_specs=(_REP, _ROW2, _ROW), check_rep=False))
+                out_specs=(_REP, _ROW2, _ROW), check_vma=False))
             self._stage2_jits["finalize"] = fn
             return fn
 
@@ -1780,7 +1785,7 @@ class FedS3ATrainer:
                 shard_fn, mesh=mesh,
                 in_specs=(_REP, _ROW2, _ROW, _REP, _REP),
                 out_specs=(_REP, _REP) + (_REP,) * n_payload,
-                check_rep=False))
+                check_vma=False))
             self._stage2_jits["finalize"] = fn
             return fn
 
@@ -1794,7 +1799,7 @@ class FedS3ATrainer:
         fn = jax.jit(shard_map(
             shard_fn, mesh=mesh,
             in_specs=(_REP, _ROW2, _ROW, _REP, _ROW2),
-            out_specs=(_REP, _ROW2, _ROW), check_rep=False))
+            out_specs=(_REP, _ROW2, _ROW), check_vma=False))
         self._stage2_jits["finalize"] = fn
         return fn
 

@@ -22,64 +22,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 
 
-def _active_mesh():
-    """Version-compat: the mesh currently in scope, or None.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on newer JAX; older
-    releases (e.g. 0.4.x) track the ``with Mesh(...):`` context through
-    ``thread_resources.env.physical_mesh`` instead.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        return None if mesh.empty else mesh
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:  # very old layout
-        from jax.interpreters.pxla import thread_resources
-    mesh = thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on newer JAX,
-    the plain ``with mesh:`` context (which pjit consults) on older JAX."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def jit_shardings(mesh, tree):
-    """PartitionSpec pytree -> whatever ``jax.jit(in_shardings=...)`` takes.
-
-    Newer JAX accepts bare PartitionSpecs under an active (set_mesh) mesh;
-    older releases require concrete ``NamedSharding``s, so bind the mesh
-    explicitly there (None leaves become fully-replicated specs).
-    """
-    if getattr(jax, "set_mesh", None) is not None:
-        return tree
-    from jax.sharding import NamedSharding
-
-    def conv(s):
-        if s is None:
-            return NamedSharding(mesh, P())
-        if isinstance(s, P):
-            return NamedSharding(mesh, s)
-        return s
-
-    return jax.tree.map(conv, tree,
-                        is_leaf=lambda s: isinstance(s, P) or s is None)
-
-
 def maybe_constraint(x, spec_dims):
     """with_sharding_constraint iff a mesh with the named axes is active.
 
     Entries may be axis names, tuples of axis names (filtered to the axes
     present on the active mesh), or None.
     """
-    mesh = _active_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
 

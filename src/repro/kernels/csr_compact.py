@@ -15,13 +15,20 @@ nnz output was designed for):
    (``keep = (|x| >= thr) & (x != 0)``; exact zeros carry no information and
    never go on the wire, unlike the sparse-delta nnz metric which counts
    every threshold survivor);
-2. exclusive scan of the counts along the block axis -> each (row, block)'s
-   global write offset;
-3. in-kernel scatter on a ``(K, ceil(N/512))`` grid: each block ranks its
-   kept elements with an in-block cumsum, packs them with a (512, 512)
-   one-hot matmul (the MXU-friendly stream-compaction idiom — Mosaic has no
-   vector scatter), and stores the packed (1, 512) window at its dynamic
-   global offset via ``pl.store``/``pl.dslice``.
+2. in-kernel pack on a ``(ceil(K / rb), ceil(N / 512))`` grid of (rb, 512)
+   blocks (rb = min(K, sublane tile): 8 rows for f32 input, 16 for bf16):
+   each block ranks its kept elements with a (rb, 512) @ (512, 512)
+   triangular-ones matmul (an inclusive in-block count — 0/1 operands, exact
+   at any MXU precision), then packs them into the front of a 512-wide
+   window: slot p takes the column that ``#{c : rank[c] <= p}`` names and
+   the value that a one-hot matmul at HIGHEST precision selects (one nonzero
+   term per slot, so the f32 value comes through exactly). Mosaic has no
+   vector scatter, so the pack is the MXU-friendly stream-compaction idiom.
+   Each block writes its window to its own 512 lanes of a (K, nblk * 512)
+   output — lane-aligned, no dynamic offsets;
+3. placement in XLA: slot s of a row lives in the block whose inclusive
+   count first exceeds s (a binary search over the exclusive scan of the
+   step-1 counts), at lane ``s - offset[block]`` of that block's window.
 
 Capacity/overflow contract: ``cap`` is the static per-row payload capacity.
 Elements with global rank >= cap fall off the end of the buffer — the
@@ -29,12 +36,6 @@ wrapper zero-masks every slot >= ``min(nnz, cap)``, and the comm layer
 spills the dropped mass into the error-feedback residual (or drops it,
 matching the paper's lossy scheme, when EF is off). The returned ``nnz`` is
 the TRUE per-row count, so callers can detect overflow (``nnz > cap``).
-
-Blocks overlap-write by construction: a block stores a full 512-wide window
-at offset ``base`` but only its first ``count`` lanes are meaningful; the
-next block's window starts at ``base + count`` and overwrites the stale
-suffix. Grid iteration over the minor (block) axis is sequential, which is
-what makes this sound.
 
 Oracle: kernels/ref.py::csr_compact2d_ref / csr_decode_ref.
 """
@@ -49,27 +50,33 @@ from jax.experimental import pallas as pl
 BLK = 512
 
 
-def _csr_scatter_kernel(n_valid, cap, x_ref, thr_ref, off_ref,
-                        vals_ref, idx_ref):
+def _csr_pack_kernel(n_valid, x_ref, thr_ref, vals_ref, idx_ref):
     j = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)               # (1, BLK)
-    thr = thr_ref[0, 0]
-    base = off_ref[0, 0]                             # global rank of this
-                                                     # block's first survivor
+    x = x_ref[...].astype(jnp.float32)                 # (rb, BLK)
     col = j * BLK + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    keep = (jnp.abs(x) >= thr) & (x != 0.0) & (col < n_valid)
-    rank = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1          # in-block
-    # one-hot pack: out[p] = x[c] where rank[c] == p (exactly one hit per
-    # occupied slot, zero elsewhere — exact, no float accumulation)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 1)
-    oh = (rank[0, :, None] == slot) & keep[0, :, None]             # (c, p)
-    vals_c = jnp.sum(oh.astype(jnp.float32) * x[0, :, None], axis=0)
-    cols_c = jnp.sum(oh.astype(jnp.int32) * col[0, :, None], axis=0)
-    # rank >= cap lands in the pad tail of the (cap + BLK) buffer; a block
-    # starting wholly past cap writes at the clamped offset (pad only)
-    wb = jnp.minimum(base, cap)
-    pl.store(vals_ref, (pl.dslice(0, 1), pl.dslice(wb, BLK)), vals_c[None, :])
-    pl.store(idx_ref, (pl.dslice(0, 1), pl.dslice(wb, BLK)), cols_c[None, :])
+    keep = (jnp.abs(x) >= thr_ref[...]) & (x != 0.0) & (col < n_valid)
+    x = jnp.where(keep, x, 0.0)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 1)
+    # inclusive in-block rank: rank[c] = #{c' <= c : keep[c']}
+    rank = jnp.dot(keep.astype(jnp.float32), (sub <= lane).astype(jnp.float32),
+                   preferred_element_type=jnp.float32)
+    ones = jnp.ones((1, BLK), jnp.float32)
+    nt = (((1,), (1,)), ((), ()))                      # contract lanes of both
+    for r in range(x.shape[0]):
+        rank_r = rank[r:r + 1, :]                  # (1, c), broadcast over p
+        # slot p's column: the number of columns whose rank is <= p
+        below = (rank_r <= sub.astype(jnp.float32)).astype(jnp.float32)
+        pos = jax.lax.dot_general(ones, below, nt,
+                                  preferred_element_type=jnp.float32)
+        # slot p's value: the kept column whose inclusive rank is p + 1
+        onehot = ((rank_r == (sub + 1).astype(jnp.float32))
+                  & keep[r:r + 1, :]).astype(jnp.float32)
+        val = jax.lax.dot_general(x[r:r + 1, :], onehot, nt,
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
+        vals_ref[r:r + 1, :] = val
+        idx_ref[r:r + 1, :] = j * BLK + pos.astype(jnp.int32)
 
 
 def csr_compact2d_pallas(x, thresholds, cap, *, interpret=True):
@@ -84,31 +91,33 @@ def csr_compact2d_pallas(x, thresholds, cap, *, interpret=True):
     K, N = x.shape
     cap = int(cap)
     assert 1 <= cap <= N, (cap, N)
-    pad = (-N) % BLK
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((K, pad), x.dtype)], axis=1)
-    nblk = (N + pad) // BLK
+    nblk = pl.cdiv(N, BLK)
     thr = jnp.asarray(thresholds, jnp.float32).reshape(K, 1)
-    # stages 1-2: per-block keep counts -> exclusive-scan write offsets
+    # stage 1: per-block keep counts
     keep = (jnp.abs(x.astype(jnp.float32)) >= thr) & (x != 0)
+    keep = jnp.pad(keep, ((0, 0), (0, nblk * BLK - N)))
     blocks = keep.reshape(K, nblk, BLK).sum(axis=2, dtype=jnp.int32)
-    offsets = jnp.cumsum(blocks, axis=1) - blocks
-    nnz = jnp.sum(blocks, axis=1)
-    cap_pad = cap + BLK                    # overflow windows land in the pad
-    vals, idx = pl.pallas_call(
-        partial(_csr_scatter_kernel, N, cap),
-        grid=(K, nblk),
-        in_specs=[pl.BlockSpec((1, BLK), lambda k, j: (k, j)),
-                  pl.BlockSpec((1, 1), lambda k, j: (k, 0)),
-                  pl.BlockSpec((1, 1), lambda k, j: (k, j))],
-        out_specs=[pl.BlockSpec((1, cap_pad), lambda k, j: (k, 0)),
-                   pl.BlockSpec((1, cap_pad), lambda k, j: (k, 0))],
-        out_shape=[jax.ShapeDtypeStruct((K, cap_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((K, cap_pad), jnp.int32)],
+    # stage 2: per-block packed windows
+    rb = min(K, 32 // x.dtype.itemsize)     # sublane tile of x's dtype
+    win = pl.BlockSpec((rb, BLK), lambda i, j: (i, j))
+    vals_w, idx_w = pl.pallas_call(
+        partial(_csr_pack_kernel, N),
+        grid=(pl.cdiv(K, rb), nblk),
+        in_specs=[win, pl.BlockSpec((rb, 1), lambda i, j: (i, 0))],
+        out_specs=[win, win],
+        out_shape=[jax.ShapeDtypeStruct((K, nblk * BLK), jnp.float32),
+                   jax.ShapeDtypeStruct((K, nblk * BLK), jnp.int32)],
         interpret=interpret,
-    )(x, thr, offsets)
-    stored = jnp.minimum(nnz, cap)
-    valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < stored[:, None]
-    vals = jnp.where(valid, vals[:, :cap], 0.0)
-    idx = jnp.where(valid, idx[:, :cap], 0)
+    )(x, thr)
+    # stage 3: slot s sits in the first block whose inclusive count > s
+    incl = jnp.cumsum(blocks, axis=1)
+    nnz = incl[:, -1]
+    slots = jnp.arange(cap, dtype=jnp.int32)
+    blk = jax.vmap(lambda c: jnp.searchsorted(c, slots, side="right"))(incl)
+    blk = jnp.minimum(blk, nblk - 1)
+    first = jnp.take_along_axis(incl - blocks, blk, axis=1)
+    src = jnp.clip(blk * BLK + slots[None, :] - first, 0, nblk * BLK - 1)
+    valid = slots[None, :] < jnp.minimum(nnz, cap)[:, None]
+    vals = jnp.where(valid, jnp.take_along_axis(vals_w, src, axis=1), 0.0)
+    idx = jnp.where(valid, jnp.take_along_axis(idx_w, src, axis=1), 0)
     return vals, idx, nnz

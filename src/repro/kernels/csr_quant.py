@@ -20,11 +20,16 @@ table. Quantization is lossy BY DESIGN: the comm layer computes the
 residual against the dequantized decode, so the rounding error joins the
 sparsification overflow in the error-feedback store and is re-sent later.
 
-One grid row per client row: the payload width ``cap`` is far smaller than
-the dense N the compaction kernel walks, so a whole (1, cap) window per
-program keeps the kernel a single fused elementwise pass (absmax reduce +
-scale + round + modulo). Oracle: ref.py::csr_quantize2d_ref /
-csr_pack_indices_ref.
+Grid: (ceil(K / 32), ceil(cap / 2048)); blocks (rb, 2048) with
+rb = min(K, 32) — the int8 sublane tile (32, also a multiple of int16's 16),
+or all K rows. The per-row absmax scale needs the whole row, so the wrapper
+computes it (one XLA reduction over the packed values, the oracle's own
+expression) and hands the kernel the (K, 1) reciprocal and stored counts; the
+kernel is then one fused elementwise pass (mask + scale + round + lane
+offset). The fp16 fallback's cast runs in XLA after the kernel: Mosaic has
+no f32 -> f16 vector pack on v5e. The block-count table (a per-row binary
+search over the ascending block ids) is also built in the wrapper.
+Oracle: ref.py::csr_quantize2d_ref / csr_pack_indices_ref.
 """
 from __future__ import annotations
 
@@ -34,28 +39,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import csr_pack_indices_ref, csr_quant_scales_ref
+
 BLK = 512
+ROWS = 32             # int8 sublane tile (int16 needs 16, f32 8)
+COLS = 2048
 
 
-def _csr_quant_kernel(q_dtype, vals_ref, idx_ref, stored_ref,
-                      q_ref, off_ref, scale_ref):
-    v = vals_ref[...].astype(jnp.float32)                # (1, cap_pad)
-    stored = stored_ref[0, 0]
-    slot = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-    valid = slot < stored
+def _csr_quant_kernel(q_dtype, cb, vals_ref, idx_ref, stored_ref, inv_ref,
+                      q_ref, off_ref):
+    j = pl.program_id(1)
+    v = vals_ref[...].astype(jnp.float32)                # (rb, cb)
+    slot = j * cb + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    valid = slot < stored_ref[...]
     v = jnp.where(valid, v, 0.0)
     if q_dtype == "fp16":
-        scale_ref[0, 0] = 1.0
-        q_ref[...] = v.astype(jnp.float16)
+        q_ref[...] = v              # v5e has no f16 vector pack: cast in XLA
     else:
-        absmax = jnp.max(jnp.abs(v))
-        scale = absmax / 127.0
-        inv = jnp.where(scale > 0, 1.0 / jnp.where(scale > 0, scale, 1.0),
-                        0.0)
-        scale_ref[0, 0] = scale
-        q_ref[...] = jnp.clip(jnp.round(v * inv), -127, 127).astype(jnp.int8)
-    idx = idx_ref[...]
-    off = idx - (idx // BLK) * BLK
+        q = jnp.clip(jnp.round(v * inv_ref[...]), -127, 127)
+        q_ref[...] = q.astype(jnp.int8)
+    off = jnp.bitwise_and(idx_ref[...], BLK - 1)         # col % 512, col >= 0
     off_ref[...] = jnp.where(valid, off, 0).astype(jnp.int16)
 
 
@@ -71,31 +74,23 @@ def csr_quantize2d_pallas(values, indices, stored, n, *, q_dtype="int8",
     """
     assert q_dtype in ("int8", "fp16"), q_dtype
     K, cap = values.shape
-    pad = (-cap) % 128                       # lane-align the row window
-    cap_pad = cap + pad
-    if pad:
-        z = jnp.zeros((K, pad), values.dtype)
-        values = jnp.concatenate([values, z], axis=1)
-        indices = jnp.concatenate(
-            [indices, jnp.zeros((K, pad), indices.dtype)], axis=1)
     stored = jnp.asarray(stored, jnp.int32)
-    out_dtype = jnp.float16 if q_dtype == "fp16" else jnp.int8
-    qvals, offs, scales = pl.pallas_call(
-        partial(_csr_quant_kernel, q_dtype),
-        grid=(K,),
-        in_specs=[pl.BlockSpec((1, cap_pad), lambda k: (k, 0)),
-                  pl.BlockSpec((1, cap_pad), lambda k: (k, 0)),
-                  pl.BlockSpec((1, 1), lambda k: (k, 0))],
-        out_specs=[pl.BlockSpec((1, cap_pad), lambda k: (k, 0)),
-                   pl.BlockSpec((1, cap_pad), lambda k: (k, 0)),
-                   pl.BlockSpec((1, 1), lambda k: (k, 0))],
-        out_shape=[jax.ShapeDtypeStruct((K, cap_pad), out_dtype),
-                   jax.ShapeDtypeStruct((K, cap_pad), jnp.int16),
-                   jax.ShapeDtypeStruct((K, 1), jnp.float32)],
+    scales, inv = csr_quant_scales_ref(values, stored, q_dtype=q_dtype)
+    rb = min(K, ROWS)
+    cb = min(cap, COLS)                      # a multiple of 128, or all cap
+    out_dtype = jnp.float32 if q_dtype == "fp16" else jnp.int8
+    row_block = pl.BlockSpec((rb, cb), lambda i, j: (i, j))
+    row_scalar = pl.BlockSpec((rb, 1), lambda i, j: (i, 0))
+    qvals, offs = pl.pallas_call(
+        partial(_csr_quant_kernel, q_dtype, cb),
+        grid=(pl.cdiv(K, rb), pl.cdiv(cap, cb)),
+        in_specs=[row_block, row_block, row_scalar, row_scalar],
+        out_specs=[row_block, row_block],
+        out_shape=[jax.ShapeDtypeStruct((K, cap), out_dtype),
+                   jax.ShapeDtypeStruct((K, cap), jnp.int16)],
         interpret=interpret,
-    )(values, indices, stored.reshape(K, 1))
-    # per-row block-count table: the cheap jnp pass csr_compact's stage 1
-    # already demonstrated; reused verbatim from the oracle
-    from repro.kernels.ref import csr_pack_indices_ref
-    _, counts = csr_pack_indices_ref(indices[:, :cap], stored, n)
-    return qvals[:, :cap], offs[:, :cap], counts, scales.reshape(K)
+    )(values, indices, stored.reshape(K, 1), inv.reshape(K, 1))
+    if q_dtype == "fp16":
+        qvals = qvals.astype(jnp.float16)
+    _, counts = csr_pack_indices_ref(indices, stored, n)
+    return qvals, offs, counts, scales

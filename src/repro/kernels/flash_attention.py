@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -66,8 +67,9 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
                            qblk=128, kblk=128, interpret=True):
     """q,k,v: (B, S, H, hd) with KV already broadcast to all H heads.
 
-    Returns (B, S, H, hd). ``interpret=True`` executes the kernel body in
-    Python on CPU (this container); on a real TPU pass interpret=False.
+    Returns (B, S, H, hd). ``interpret=True`` runs the kernel in the Pallas
+    interpreter (any backend); ``interpret=False`` compiles it with Mosaic
+    for a TPU.
     """
     B, S, H, hd = q.shape
     qblk = min(qblk, S)
@@ -94,19 +96,11 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
         out_specs=pl.BlockSpec((1, qblk, hd), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         scratch_shapes=[
-            pl.ScratchShape((qblk, hd), jnp.float32),
-            pl.ScratchShape((qblk,), jnp.float32),
-            pl.ScratchShape((qblk,), jnp.float32),
-        ] if hasattr(pl, "ScratchShape") else _tpu_scratch(qblk, hd),
+            pltpu.VMEM((qblk, hd), jnp.float32),
+            pltpu.VMEM((qblk,), jnp.float32),
+            pltpu.VMEM((qblk,), jnp.float32),
+        ],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
-
-def _tpu_scratch(qblk, hd):
-    from jax.experimental.pallas import tpu as pltpu
-    return [
-        pltpu.VMEM((qblk, hd), jnp.float32),
-        pltpu.VMEM((qblk,), jnp.float32),
-        pltpu.VMEM((qblk,), jnp.float32),
-    ]

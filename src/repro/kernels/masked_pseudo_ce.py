@@ -6,8 +6,12 @@ The unfused jnp version makes three HBM round-trips over (N, C) logits
 (softmax, max, gather); on large unlabeled client batches this layer is the
 training hot spot of the FedS3A client step.
 
-Grid: (N // blk,); block (blk, C_pad) in VMEM. C is padded to the 128-lane
-width by the wrapper (padded classes get -inf logits).
+Grid: (ceil(N / blk),) over the transposed logits: the wrapper hands the
+kernel (C, N), so a block (C, blk) holds blk samples along the 128 lanes and
+the class reductions run down the sublanes. Loss and mask come out as
+lane-dense (1, N) rows, reshaped to (N,) by the wrapper. The block covers
+all C classes, so no class padding is needed, and a partial tail block's
+out-of-range samples are never written back.
 
 Oracle: kernels/ref.py::masked_pseudo_ce_ref.
 """
@@ -21,11 +25,10 @@ from jax.experimental import pallas as pl
 
 
 def _pseudo_ce_kernel(logits_ref, loss_ref, mask_ref, *, threshold):
-    x = logits_ref[...].astype(jnp.float32)          # (blk, C_pad)
-    m = jnp.max(x, axis=1)
-    lse = m + jnp.log(jnp.sum(jnp.exp(x - m[:, None]), axis=1))
-    max_logp = m - lse                               # log max softmax
-    mask = (max_logp >= jnp.log(threshold)).astype(jnp.float32)
+    x = logits_ref[...].astype(jnp.float32)          # (C, blk)
+    m = jnp.max(x, axis=0, keepdims=True)
+    max_logp = -jnp.log(jnp.sum(jnp.exp(x - m), axis=0, keepdims=True))
+    mask = (jnp.exp(max_logp) >= threshold).astype(jnp.float32)
     loss_ref[...] = -mask * max_logp
     mask_ref[...] = mask
 
@@ -33,23 +36,16 @@ def _pseudo_ce_kernel(logits_ref, loss_ref, mask_ref, *, threshold):
 def masked_pseudo_ce_pallas(logits, threshold, *, blk=256, interpret=True):
     """logits: (N, C). Returns (loss (N,), mask (N,))."""
     N, C = logits.shape
-    C_pad = max(128, ((C + 127) // 128) * 128)
-    blk = min(blk, N)
-    if N % blk:
-        blk = N  # fall back to one block
-    if C_pad != C:
-        pad = jnp.full((N, C_pad - C), -1e30, logits.dtype)
-        logits = jnp.concatenate([logits, pad], axis=1)
-
+    blk = N if N <= blk else blk        # blk: a multiple of 128, or all N
     kernel = functools.partial(_pseudo_ce_kernel, threshold=threshold)
     loss, mask = pl.pallas_call(
         kernel,
-        grid=(N // blk,),
-        in_specs=[pl.BlockSpec((blk, C_pad), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((blk,), lambda i: (i,)),
-                   pl.BlockSpec((blk,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.float32),
-                   jax.ShapeDtypeStruct((N,), jnp.float32)],
+        grid=(pl.cdiv(N, blk),),
+        in_specs=[pl.BlockSpec((C, blk), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((1, blk), lambda i: (0, i)),
+                   pl.BlockSpec((1, blk), lambda i: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((1, N), jnp.float32),
+                   jax.ShapeDtypeStruct((1, N), jnp.float32)],
         interpret=interpret,
-    )(logits)
-    return loss, mask
+    )(logits.T)
+    return loss.reshape(N), mask.reshape(N)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode; on TPU they lower
-to Mosaic. ``masked_pseudo_ce`` carries a custom VJP so the FedS3A client loss
-is differentiable (backward is the standard (p - onehot) * mask softmax grad).
+On a TPU the kernels compile with Mosaic; on any other backend (the CPU test
+suite) they run in the Pallas interpreter. ``masked_pseudo_ce`` carries a
+custom VJP so the FedS3A client loss is differentiable (backward is the
+standard (p - onehot) * mask softmax grad).
 """
 from __future__ import annotations
 
@@ -110,9 +111,4 @@ def csr_decode(values, indices, n):
 
 def staleness_agg(deltas, weights):
     """(K, N) stacked deltas x (K,) weights -> (N,) fp32 weighted sum."""
-    k, n = deltas.shape
-    pad = (-n) % 512
-    if pad:
-        deltas = jnp.concatenate(
-            [deltas, jnp.zeros((k, pad), deltas.dtype)], axis=1)
-    return staleness_agg_pallas(deltas, weights, interpret=_interpret())[:n]
+    return staleness_agg_pallas(deltas, weights, interpret=_interpret())

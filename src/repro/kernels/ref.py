@@ -132,6 +132,25 @@ def csr_decode_ref(values, indices, n):
         values.astype(jnp.float32))
 
 
+def csr_quant_scales_ref(values, stored, *, q_dtype="int8"):
+    """Per-row (scales, reciprocals) of the ``csr_q`` quantizer.
+
+    values: (K, cap) packed f32 payload values; stored: (K,) int32 valid
+    prefix lengths. ``scale = absmax / 127`` over the stored prefix and
+    ``inv = 1 / scale`` (0 for an all-zero row); fp16 rows carry ones.
+    """
+    K, cap = values.shape
+    if q_dtype == "fp16":
+        ones = jnp.ones((K,), jnp.float32)
+        return ones, ones
+    valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < \
+        jnp.asarray(stored, jnp.int32)[:, None]
+    v = jnp.where(valid, values.astype(jnp.float32), 0.0)
+    scale = jnp.max(jnp.abs(v), axis=1) / 127.0
+    inv = jnp.where(scale > 0, 1.0 / jnp.where(scale > 0, scale, 1.0), 0.0)
+    return scale, inv
+
+
 def csr_quantize2d_ref(values, stored, *, q_dtype="int8"):
     """Per-row absmax quantization of packed CSR values (``csr_q`` format).
 
@@ -150,15 +169,13 @@ def csr_quantize2d_ref(values, stored, *, q_dtype="int8"):
     ``delta - dequant(decode(payload))`` into the error-feedback residual,
     so the loss is re-sent later rather than forgotten.
     """
-    K, cap = values.shape
+    cap = values.shape[1]
     valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < \
         jnp.asarray(stored, jnp.int32)[:, None]
     v = jnp.where(valid, values.astype(jnp.float32), 0.0)
+    scale, inv = csr_quant_scales_ref(values, stored, q_dtype=q_dtype)
     if q_dtype == "fp16":
-        return v.astype(jnp.float16), jnp.ones((K,), jnp.float32)
-    absmax = jnp.max(jnp.abs(v), axis=1)
-    scale = absmax / 127.0
-    inv = jnp.where(scale > 0, 1.0 / jnp.where(scale > 0, scale, 1.0), 0.0)
+        return v.astype(jnp.float16), scale
     q = jnp.clip(jnp.round(v * inv[:, None]), -127, 127).astype(jnp.int8)
     return q, scale
 
@@ -200,9 +217,13 @@ def csr_pack_indices_ref(indices, stored, n):
     valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < \
         jnp.asarray(stored, jnp.int32)[:, None]
     offs = jnp.where(valid, indices % blk, 0).astype(jnp.int16)
-    blk_id = jnp.where(valid, indices // blk, nblk)   # pad -> out of range
-    counts = (blk_id[:, :, None] ==
-              jnp.arange(nblk, dtype=jnp.int32)[None, None, :]).sum(axis=1)
+    blk_id = jnp.where(valid, indices // blk, nblk)   # pad -> past the end
+    # blk_id ascends along each row, so the number of slots in blocks <= b
+    # is a binary search: counts are the differences of that cumulative
+    # histogram, O(nblk log cap) per row
+    upto = jax.vmap(lambda r: jnp.searchsorted(
+        r, jnp.arange(nblk, dtype=jnp.int32), side="right"))(blk_id)
+    counts = jnp.diff(upto, axis=1, prepend=0)
     return offs, counts.astype(jnp.int16)
 
 

@@ -21,11 +21,17 @@ Three entry points share one kernel body:
   is invariant to how rows are sharded across devices.
 * ``sparse_delta_pallas`` — the original single-delta form, the K=1 case.
 
-Grid: (K, ceil(N / 512)); blocks (1, 512) — 512 = 4 * 128 lanes — with the
-threshold in a (1, 1) block per grid row. N that is not a multiple of 512 is
-zero-padded here, and the kernel masks the pad columns out of the nnz count
-(an in-kernel column-index guard), so degenerate all-pass thresholds
-(thr <= 0) do not overcount the pad.
+Grid: (ceil(K / rb), ceil(N / 512)); x blocks (rb, 512) — 512 = 4 * 128
+lanes, rb = min(K, sublane tile) rows (8 for f32, 16 for bf16) so the
+second-minor block dimension is a multiple of the tile or covers all K
+rows. Thresholds ride in an (rb, 1) block per grid row. The per-block counts are written lane-dense: the
+(rb, 128) nnz output block of column-block j covers blocks
+[128 * (j // 128), 128 * (j // 128) + 128) and stays resident in VMEM while
+the sequential minor grid axis fills lane ``j % 128``. Partial tail blocks
+(N not a multiple of 512, K not a multiple of rb) need no padding: the
+kernel masks columns >= N out of the count (an in-kernel column-index
+guard), so degenerate all-pass thresholds (thr <= 0) do not overcount, and
+out-of-range rows and columns are never written back.
 
 Oracle: kernels/ref.py::sparse_delta_ref / sparse_delta2d_ref.
 """
@@ -38,44 +44,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLK = 512
+LANES = 128
 QUANTILE_SAMPLE = 2048
 
 
 def _sparse_delta_kernel(n_valid, x_ref, thr_ref, out_ref, nnz_ref):
     j = pl.program_id(1)
-    x = x_ref[...]                                   # (1, BLK)
-    thr = thr_ref[0, 0]
+    x = x_ref[...]                                   # (rb, BLK)
     col = j * BLK + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    keep = (jnp.abs(x.astype(jnp.float32)) >= thr) & (col < n_valid)
+    keep = (jnp.abs(x.astype(jnp.float32)) >= thr_ref[...]) & (col < n_valid)
     out_ref[...] = jnp.where(keep, x, 0).astype(out_ref.dtype)
-    nnz_ref[...] = jnp.sum(keep.astype(jnp.int32), axis=1, keepdims=True)
+    cnt = jnp.sum(keep.astype(jnp.int32), axis=1, keepdims=True)   # (rb, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, nnz_ref.shape, 1)
+
+    @pl.when(j % LANES == 0)
+    def _():
+        nnz_ref[...] = jnp.zeros_like(nnz_ref)
+
+    nnz_ref[...] = jnp.where(lane == j % LANES, cnt, nnz_ref[...])
 
 
 def sparse_delta2d_pallas(x, thresholds, *, interpret=True):
     """x: (K, N), any N; thresholds: (K,) runtime scalars.
 
     Returns (masked (K, N), nnz (K, ceil(N/512)) int32) — every client's
-    delta is masked against its own threshold in one kernel launch. Pad
-    columns (to the 512 block) are excluded from the count in-kernel.
+    delta is masked against its own threshold in one kernel launch. Columns
+    past N (the tail block's pad) are excluded from the count in-kernel.
     """
     K, N = x.shape
-    pad = (-N) % BLK
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((K, pad), x.dtype)], axis=1)
-    nblk = (N + pad) // BLK
+    nblk = pl.cdiv(N, BLK)
+    rb = min(K, 32 // x.dtype.itemsize)     # sublane tile of x's dtype
     thresholds = jnp.asarray(thresholds, jnp.float32).reshape(K, 1)
     masked, nnz = pl.pallas_call(
         partial(_sparse_delta_kernel, N),
-        grid=(K, nblk),
-        in_specs=[pl.BlockSpec((1, BLK), lambda k, j: (k, j)),
-                  pl.BlockSpec((1, 1), lambda k, j: (k, 0))],
-        out_specs=[pl.BlockSpec((1, BLK), lambda k, j: (k, j)),
-                   pl.BlockSpec((1, 1), lambda k, j: (k, j))],
-        out_shape=[jax.ShapeDtypeStruct((K, N + pad), x.dtype),
-                   jax.ShapeDtypeStruct((K, nblk), jnp.int32)],
+        grid=(pl.cdiv(K, rb), nblk),
+        in_specs=[pl.BlockSpec((rb, BLK), lambda i, j: (i, j)),
+                  pl.BlockSpec((rb, 1), lambda i, j: (i, 0))],
+        out_specs=[pl.BlockSpec((rb, BLK), lambda i, j: (i, j)),
+                   pl.BlockSpec((rb, LANES), lambda i, j: (i, j // LANES))],
+        out_shape=[jax.ShapeDtypeStruct((K, N), x.dtype),
+                   jax.ShapeDtypeStruct((K, pl.cdiv(nblk, LANES) * LANES),
+                                        jnp.int32)],
         interpret=interpret,
     )(x, thresholds)
-    return masked[:, :N], nnz
+    return masked, nnz[:, :nblk]
 
 
 def local_quantile_thresholds(x, keep_frac, *, sample=QUANTILE_SAMPLE):

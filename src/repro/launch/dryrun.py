@@ -22,7 +22,6 @@ from repro.analysis import roofline as RL                     # noqa: E402
 from repro.configs import INPUT_SHAPES, get_config, list_configs  # noqa: E402
 from repro.launch.mesh import make_production_mesh            # noqa: E402
 from repro.launch.specs import build_case                     # noqa: E402
-from repro.distributed.sharding import jit_shardings, use_mesh  # noqa: E402
 
 
 def run_one(arch, shape_name, *, multi_pod=False, fsdp=True, moe_impl="einsum",
@@ -36,9 +35,9 @@ def run_one(arch, shape_name, *, multi_pod=False, fsdp=True, moe_impl="einsum",
                       seq_parallel=seq_parallel, capacity_factor=capacity_factor,
                       serve_profile=serve_profile)
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(case.step_fn,
-                         in_shardings=jit_shardings(mesh, case.in_shardings))
+                         in_shardings=case.in_shardings)
         lowered = jitted.lower(*case.args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -16,6 +16,8 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
+
 
 def run_fl(args):
     from repro.checkpoint import save_checkpoint
@@ -74,6 +76,7 @@ def run_lm(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="mode", required=True)
 

@@ -24,7 +24,6 @@ import jax
 from repro.configs import get_config
 from repro.launch.mesh import make_test_mesh
 from repro.launch.specs import build_case
-from repro.distributed.sharding import jit_shardings, use_mesh
 mesh = make_test_mesh((2, 2), ("data", "model"))
 for arch in ("qwen2-1.5b", "xlstm-125m"):
     cfg = get_config(arch).reduced()
@@ -32,9 +31,9 @@ for arch in ("qwen2-1.5b", "xlstm-125m"):
     cfg = dataclasses.replace(cfg, name=cfg.name)
     for shape in ("train_4k", "decode_32k"):
         case = build_case(cfg, shape, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(case.step_fn,
-                        in_shardings=jit_shardings(mesh, case.in_shardings)
+                        in_shardings=case.in_shardings
                         ).lower(*case.args).compile()
         assert c.memory_analysis() is not None
         print("OK", arch, shape)

@@ -458,7 +458,7 @@ def test_sparse_encode_shard_invariant():
     the (K, N) stack is encoded whole or row-sharded across the client
     mesh — thresholds are per-row statistics, so shard_map adds no
     cross-device coupling."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.sparse_comm import SparseComm
@@ -476,7 +476,7 @@ def test_sparse_encode_shard_invariant():
         core, mesh=mesh,
         in_specs=(P(CLIENT_AXIS, None), P(CLIENT_AXIS, None)),
         out_specs=(P(CLIENT_AXIS, None), P(CLIENT_AXIS)),
-        check_rep=False))
+        check_vma=False))
     sh_masked, sh_nnz = sharded(new, base)
     np.testing.assert_allclose(np.asarray(sh_masked),
                                np.asarray(whole_masked), atol=1e-7)
@@ -487,7 +487,7 @@ def test_sparse_encode_shard_invariant():
 def test_staleness_agg_psum_matches_whole():
     """blend_flat_sharded's psum-of-local-weighted-sums == the unsharded
     weighted sum, to reduction-order tolerance."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import aggregation as agg
@@ -507,7 +507,7 @@ def test_staleness_agg_psum_matches_whole():
     out = jax.jit(shard_map(
         stage, mesh=mesh,
         in_specs=(P(), P(CLIENT_AXIS, None), P(CLIENT_AXIS), P()),
-        out_specs=P(), check_rep=False))(server, deltas, w, fw)
+        out_specs=P(), check_vma=False))(server, deltas, w, fw)
     expect = 0.35 * np.asarray(server) + 0.65 * np.einsum(
         "k,kn->n", np.asarray(w), np.asarray(deltas))
     np.testing.assert_allclose(np.asarray(out), expect, rtol=2e-5, atol=2e-5)
