@@ -1,0 +1,11 @@
+"""Share of the traced window in which no operation ran on the device
+(averaged over the chips used)."""
+UNIT = "%"
+LAYER = "device"
+MOVES = "round_s"
+SOURCE = "device_trace"
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
