@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sparse_comm import flatten_tree, unflatten_like
+from repro.core.telemetry import UNPACK, scope
 from repro.kernels import ops as kops
 
 
@@ -191,12 +192,14 @@ def csr_q_weighted_scatter(qvals, qoffs, qcnt, scales, w, n):
     """
     K, cap = qoffs.shape
     nblk = qcnt.shape[1]
-    cum = jnp.cumsum(qcnt.astype(jnp.int32), axis=1)
-    slots = jnp.arange(cap, dtype=jnp.int32)
-    blk = jax.vmap(lambda c: jnp.searchsorted(c, slots, side="right"))(cum)
-    idx = jnp.minimum(blk, nblk - 1).astype(jnp.int32) * 512 + \
-        qoffs.astype(jnp.int32)
-    idx = jnp.minimum(idx, n - 1)
+    with scope(UNPACK):
+        cum = jnp.cumsum(qcnt.astype(jnp.int32), axis=1)
+        slots = jnp.arange(cap, dtype=jnp.int32)
+        blk = jax.vmap(
+            lambda c: jnp.searchsorted(c, slots, side="right"))(cum)
+        idx = jnp.minimum(blk, nblk - 1).astype(jnp.int32) * 512 + \
+            qoffs.astype(jnp.int32)
+        idx = jnp.minimum(idx, n - 1)
     contrib = (w.astype(jnp.float32) *
                scales.astype(jnp.float32))[:, None] * \
         qvals.astype(jnp.float32)

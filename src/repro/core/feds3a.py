@@ -71,6 +71,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.feds3a_cnn import CONFIG as CNN_CONFIG
 from repro.core import aggregation as agg
 from repro.core import fleet_ckpt
+from repro.core import telemetry as tm
 from repro.core.base_store import VersionedBaseStore
 from repro.core.client_store import PagedClientStore
 from repro.core.functions import (adaptive_learning_rates, staleness_fn,
@@ -726,9 +727,11 @@ class FedS3ATrainer:
             core = self.comm.csr_core(False)
 
             def body(new_flat, prev):
-                payload, stored, decoded = core(new_flat[None], prev[None])
-                return prev + decoded[0], \
-                    tuple(p[0] for p in payload) + (stored[0],)
+                with tm.scope(tm.DISTRIBUTE):
+                    payload, stored, decoded = core(new_flat[None],
+                                                    prev[None])
+                    return prev + decoded[0], \
+                        tuple(p[0] for p in payload) + (stored[0],)
 
             return body
         core = self.comm.batch_core(False) if self.comm.enabled else None
@@ -736,8 +739,9 @@ class FedS3ATrainer:
         def body(new_flat, prev):
             if core is None:
                 return new_flat, ()
-            masked, nnz = core(new_flat[None], prev[None])
-            return prev + masked[0], (nnz[0],)
+            with tm.scope(tm.DISTRIBUTE):
+                masked, nnz = core(new_flat[None], prev[None])
+                return prev + masked[0], (nnz[0],)
 
         return body
 
@@ -900,13 +904,14 @@ class FedS3ATrainer:
 
     # ------------------------------------------------------------------
     def run_round(self):
-        if self.chunked:
-            return self._run_round_chunked()
-        if self.engine == "sharded":
-            return self._run_round_sharded()
-        if self.engine == "batched":
-            return self._run_round_batched()
-        return self._run_round_sequential()
+        with tm.span(tm.ROUND):
+            if self.chunked:
+                return self._run_round_chunked()
+            if self.engine == "sharded":
+                return self._run_round_sharded()
+            if self.engine == "batched":
+                return self._run_round_batched()
+            return self._run_round_sequential()
 
     def _round_prologue(self):
         """Advance the scheduler one boundary. Returns ``(prev_time, ev,
@@ -914,38 +919,42 @@ class FedS3ATrainer:
         staleness / forced restarts plus the fault-layer consequences
         (lost uploads, churn, degradation) every engine threads through
         the same distribution plan."""
-        prev_time = self.scheduler.state.time
-        if self.paged:
-            # swap point of the page double-buffer: the previous round's
-            # queued residual writebacks / retirements have overlapped the
-            # inter-round host work; drain them before this round gathers
-            self.cstore.flush()
-        ev = self.scheduler.next_round()
-        self._quarantine_uploads(ev)
-        lrs = adaptive_learning_rates(
-            self.participation, base_lr=self.cfg.lr,
-            round_weight=self.cfg.round_weight_function,
-            adaptive=self.cfg.adaptive_lr)
+        with tm.span(tm.PROLOGUE):
+            prev_time = self.scheduler.state.time
+            if self.paged:
+                # swap point of the page double-buffer: the previous round's
+                # queued residual writebacks / retirements have overlapped
+                # the inter-round host work; drain them before this round
+                # gathers
+                self.cstore.flush()
+            with tm.span(tm.SCHEDULER):
+                ev = self.scheduler.next_round()
+            self._quarantine_uploads(ev)
+            lrs = adaptive_learning_rates(
+                self.participation, base_lr=self.cfg.lr,
+                round_weight=self.cfg.round_weight_function,
+                adaptive=self.cfg.adaptive_lr)
         return prev_time, ev, lrs
 
     def _round_epilogue(self, prev_time, ev):
-        part_ids = [run.client for run in ev.participants]
-        row = np.zeros((1, self.M))
-        row[0, part_ids] = 1
-        self.participation = np.concatenate([self.participation, row])
-        if self.paged:
-            self.cstore.record_participation(part_ids,
-                                             self.global_version - 1)
-        log = RoundLog(round=self.global_version - 1, time=ev.time,
-                       art=ev.time - prev_time, participants=part_ids,
-                       stalenesses={i: ev.stale[i] for i in part_ids},
-                       forced=ev.forced, degraded=ev.degraded,
-                       deadline_hit=ev.deadline_hit, quorum=ev.quorum,
-                       target_k=ev.target_k, crashes=ev.crashes,
-                       lost=ev.lost, departed=ev.departed,
-                       rejoined=ev.rejoined, resynced=ev.resynced,
-                       corrupted=ev.corrupted)
-        self.logs.append(log)
+        with tm.span(tm.EPILOGUE):
+            part_ids = [run.client for run in ev.participants]
+            row = np.zeros((1, self.M))
+            row[0, part_ids] = 1
+            self.participation = np.concatenate([self.participation, row])
+            if self.paged:
+                self.cstore.record_participation(part_ids,
+                                                 self.global_version - 1)
+            log = RoundLog(round=self.global_version - 1, time=ev.time,
+                           art=ev.time - prev_time, participants=part_ids,
+                           stalenesses={i: ev.stale[i] for i in part_ids},
+                           forced=ev.forced, degraded=ev.degraded,
+                           deadline_hit=ev.deadline_hit, quorum=ev.quorum,
+                           target_k=ev.target_k, crashes=ev.crashes,
+                           lost=ev.lost, departed=ev.departed,
+                           rejoined=ev.rejoined, resynced=ev.resynced,
+                           corrupted=ev.corrupted)
+            self.logs.append(log)
         return log
 
     def _server_step(self):
@@ -1048,9 +1057,11 @@ class FedS3ATrainer:
                 def s(c, _):
                     c, k = jax.random.split(c)
                     return c, k
-                return jax.lax.scan(s, rng, None, length=K)
+                with tm.scope(tm.KEYS):
+                    return jax.lax.scan(s, rng, None, length=K)
             self._key_jits[K] = fn
-        self.rng, keys = fn(self.rng)
+        with tm.span(tm.KEYS):
+            self.rng, keys = fn(self.rng)
         return keys
 
     def _encode_upload_body(self, with_residual, with_hist):
@@ -1072,17 +1083,25 @@ class FedS3ATrainer:
         Legacy dense-masked format returns (uploaded, nnz, hists|None,
         new_res|None) as before."""
         hist = self.histogram_batch
+
+        def histograms(uploaded, xs, vs):
+            if not with_hist:
+                return None
+            with tm.scope(tm.HISTOGRAM):
+                return hist(uploaded, xs, vs)
+
         if self._csr_wire:
             core = self.comm.csr_core(with_residual)
 
             def body(trained, base, xs, vs, residual=None):
-                if with_residual:
-                    payload, stored, decoded, res_payload, res_dense = \
-                        core(trained, base, residual)
-                else:
-                    payload, stored, decoded = core(trained, base)
-                    res_payload = res_dense = None
-                hists = hist(base + decoded, xs, vs) if with_hist else None
+                with tm.scope(tm.UPLOAD):
+                    if with_residual:
+                        payload, stored, decoded, res_payload, res_dense = \
+                            core(trained, base, residual)
+                    else:
+                        payload, stored, decoded = core(trained, base)
+                        res_payload = res_dense = None
+                    hists = histograms(base + decoded, xs, vs)
                 return payload, stored, hists, res_payload, res_dense
 
             return body
@@ -1090,20 +1109,22 @@ class FedS3ATrainer:
             else None
 
         def body(trained, base, xs, vs, residual=None):
-            if core is None:
-                delta = trained - base
-                if with_residual:
-                    delta = delta + residual
-                masked, nnz = delta, jnp.full((trained.shape[0],),
-                                              trained.shape[1])
-                new_res = jnp.zeros_like(delta) if with_residual else None
-            elif with_residual:
-                masked, nnz, new_res = core(trained, base, residual)
-            else:
-                masked, nnz = core(trained, base)
-                new_res = None
-            uploaded = base + masked
-            hists = hist(uploaded, xs, vs) if with_hist else None
+            with tm.scope(tm.UPLOAD):
+                if core is None:
+                    delta = trained - base
+                    if with_residual:
+                        delta = delta + residual
+                    masked, nnz = delta, jnp.full((trained.shape[0],),
+                                                  trained.shape[1])
+                    new_res = jnp.zeros_like(delta) if with_residual \
+                        else None
+                elif with_residual:
+                    masked, nnz, new_res = core(trained, base, residual)
+                else:
+                    masked, nnz = core(trained, base)
+                    new_res = None
+                uploaded = base + masked
+                hists = histograms(uploaded, xs, vs)
             return uploaded, nnz, hists, new_res
 
         return body
@@ -1119,9 +1140,10 @@ class FedS3ATrainer:
             core = self.comm.csr_core(False)
 
             def body(new_flat, dist_base):
-                g = jnp.broadcast_to(new_flat, dist_base.shape)
-                _payload, stored, decoded = core(g, dist_base)
-                return dist_base + decoded, stored
+                with tm.scope(tm.DISTRIBUTE):
+                    g = jnp.broadcast_to(new_flat, dist_base.shape)
+                    _payload, stored, decoded = core(g, dist_base)
+                    return dist_base + decoded, stored
 
             return body
         core = self.comm.batch_core(False) if self.comm.enabled else None
@@ -1133,8 +1155,9 @@ class FedS3ATrainer:
                 # base is an exact copy (dist_base + (g - dist_base)
                 # re-rounds; g itself does not)
                 return g, jnp.full((dist_base.shape[0],), new_flat.shape[0])
-            masked, nnz = core(g, dist_base)
-            return dist_base + masked, nnz
+            with tm.scope(tm.DISTRIBUTE):
+                masked, nnz = core(g, dist_base)
+                return dist_base + masked, nnz
 
         return body
 
@@ -1201,26 +1224,31 @@ class FedS3ATrainer:
 
             @jax.jit
             def fn(server_flat, base_flat, payload, w, fw, dist_base):
-                new_flat = blend(server_flat, base_flat, payload, w, fw)
-                if versioned:
-                    recon, payload = distribute(new_flat, dist_base)
-                    return (new_flat, recon) + payload
-                new_base, nnz = distribute(new_flat, dist_base)
-                return new_flat, new_base, nnz
+                with tm.scope(tm.FINALIZE):
+                    with tm.scope(tm.BLEND):
+                        new_flat = blend(server_flat, base_flat, payload, w,
+                                         fw)
+                    if versioned:
+                        recon, payload = distribute(new_flat, dist_base)
+                        return (new_flat, recon) + payload
+                    new_base, nnz = distribute(new_flat, dist_base)
+                    return new_flat, new_base, nnz
         else:
             @jax.jit
             def fn(server_flat, uploaded, w, fw, dist_base):
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    unsup = kops.staleness_agg(uploaded, w)
-                else:
-                    unsup = jnp.einsum("k,kn->n", w, uploaded)
-                new_flat = fw * server_flat + (1.0 - fw) * unsup
-                if versioned:
-                    recon, payload = distribute(new_flat, dist_base)
-                    return (new_flat, recon) + payload
-                new_base, nnz = distribute(new_flat, dist_base)
-                return new_flat, new_base, nnz
+                with tm.scope(tm.FINALIZE):
+                    with tm.scope(tm.BLEND):
+                        if use_kernel:
+                            from repro.kernels import ops as kops
+                            unsup = kops.staleness_agg(uploaded, w)
+                        else:
+                            unsup = jnp.einsum("k,kn->n", w, uploaded)
+                        new_flat = fw * server_flat + (1.0 - fw) * unsup
+                    if versioned:
+                        recon, payload = distribute(new_flat, dist_base)
+                        return (new_flat, recon) + payload
+                    new_base, nnz = distribute(new_flat, dist_base)
+                    return new_flat, new_base, nnz
 
         self._finalize_jit = fn
         return fn
@@ -1245,79 +1273,79 @@ class FedS3ATrainer:
         # epoch compiles exactly once; all-padding batches are skipped by
         # the in-graph cond, so each client still pays for exactly its own
         # number of optimizer steps
-        xs, vs = self._gather_data(part_ids)
-        if self.base_store == "versioned":
-            # version-indexed base gather from the (tau+2, N) ring — no
-            # per-client rows exist
-            base_flat = self.store.gather(part_ids)
-        else:
-            base_flat = jnp.stack([self._base_rows[i] for i in part_ids])
+        with tm.span(tm.GATHER):
+            xs, vs = self._gather_data(part_ids)
+            if self.base_store == "versioned":
+                # version-indexed base gather from the (tau+2, N) ring — no
+                # per-client rows exist
+                base_flat = self.store.gather(part_ids)
+            else:
+                base_flat = jnp.stack([self._base_rows[i] for i in part_ids])
 
-        trained_flat, _ = self.batched_epoch(base_flat, xs, vs,
-                                             lrs[part_ids], keys)
+        with tm.span(tm.CLIENT_EPOCH):
+            trained_flat, loss, live = self.batched_epoch(
+                base_flat, xs, vs, lrs[part_ids], keys)
+            self._count_epoch(loss, live, xs)
 
         with_hist = cfg.group_based and K > 1
         n = trained_flat.shape[1]
-        if self._csr_wire:
-            # the upload stage emits the compacted payload; the dense
-            # uploaded stack never leaves the jit (histograms consume it
-            # in-graph, aggregation takes base + payload)
-            if cfg.error_feedback and self.paged:
-                # residual pages in, residual pages out: the participant
-                # window decodes to dense inside the jit (fused with the
-                # encode) and the new CSR pages join the writeback queue
-                rv, rx = self.cstore.gather_csr(part_ids)
-                payload, nnz, hists_dev, (nrv, nrx) = self._upload_fn_paged(
-                    with_hist)(trained_flat, base_flat, xs, vs, rv, rx)
-                self.cstore.scatter_csr(part_ids, nrv, nrx)
+        with tm.span(tm.UPLOAD):
+            if self._csr_wire:
+                # the upload stage emits the compacted payload; the dense
+                # uploaded stack never leaves the jit (histograms consume
+                # it in-graph, aggregation takes base + payload)
+                if cfg.error_feedback and self.paged:
+                    # residual pages in, residual pages out: the
+                    # participant window decodes to dense inside the jit
+                    # (fused with the encode) and the new CSR pages join
+                    # the writeback queue
+                    rv, rx = self.cstore.gather_csr(part_ids)
+                    payload, nnz, hists_dev, (nrv, nrx) = \
+                        self._upload_fn_paged(with_hist)(
+                            trained_flat, base_flat, xs, vs, rv, rx)
+                    self.cstore.scatter_csr(part_ids, nrv, nrx)
+                elif cfg.error_feedback:
+                    residual = jnp.stack(
+                        [self._residual_rows[i] for i in part_ids])
+                    payload, nnz, hists_dev, _, res_dense = self._upload_fn(
+                        True, with_hist)(trained_flat, base_flat, xs, vs,
+                                         residual)
+                    for row, i in enumerate(part_ids):
+                        self._residual_rows[i] = res_dense[row]
+                else:
+                    payload, nnz, hists_dev, _, _ = self._upload_fn(
+                        False, with_hist)(trained_flat, base_flat, xs, vs)
+                self.comm.account_batch_csr(nnz, n, K)
+            elif cfg.error_feedback and self.paged:
+                residual = self.cstore.gather_dense(part_ids)
+                uploaded_flat, nnz, hists_dev, residual = self._upload_fn(
+                    True, with_hist)(trained_flat, base_flat, xs, vs,
+                                     residual)
+                self.cstore.scatter_dense(part_ids, residual)
+                self.comm.account_batch(nnz, n, K)
             elif cfg.error_feedback:
                 residual = jnp.stack(
                     [self._residual_rows[i] for i in part_ids])
-                payload, nnz, hists_dev, _, res_dense = self._upload_fn(
+                uploaded_flat, nnz, hists_dev, residual = self._upload_fn(
                     True, with_hist)(trained_flat, base_flat, xs, vs,
                                      residual)
                 for row, i in enumerate(part_ids):
-                    self._residual_rows[i] = res_dense[row]
+                    self._residual_rows[i] = residual[row]
+                self.comm.account_batch(nnz, n, K)
             else:
-                payload, nnz, hists_dev, _, _ = self._upload_fn(
+                uploaded_flat, nnz, hists_dev, _ = self._upload_fn(
                     False, with_hist)(trained_flat, base_flat, xs, vs)
-            self.comm.account_batch_csr(nnz, n, K)
-        elif cfg.error_feedback and self.paged:
-            residual = self.cstore.gather_dense(part_ids)
-            uploaded_flat, nnz, hists_dev, residual = self._upload_fn(
-                True, with_hist)(trained_flat, base_flat, xs, vs, residual)
-            self.cstore.scatter_dense(part_ids, residual)
-            self.comm.account_batch(nnz, n, K)
-        elif cfg.error_feedback:
-            residual = jnp.stack([self._residual_rows[i] for i in part_ids])
-            uploaded_flat, nnz, hists_dev, residual = self._upload_fn(
-                True, with_hist)(trained_flat, base_flat, xs, vs, residual)
-            for row, i in enumerate(part_ids):
-                self._residual_rows[i] = residual[row]
-            self.comm.account_batch(nnz, n, K)
-        else:
-            uploaded_flat, nnz, hists_dev, _ = self._upload_fn(
-                False, with_hist)(trained_flat, base_flat, xs, vs)
-            self.comm.account_batch(nnz, n, K)
+                self.comm.account_batch(nnz, n, K)
 
-        # server supervised epoch on the current global model (Eq. 6), in
-        # flat space; the RNG split order matches the sequential path
-        self.rng, k = jax.random.split(self.rng)
-        sp_flat, self.server_opt, _ = self.server_epoch_flat(
-            self._global_flat, self.server_opt,
-            self.data["server"]["x"], self.data["server"]["y"], cfg.lr, k)
+        sp_flat = self._server_epoch_flat()
+        groups = self._group(hists_dev, K) if with_hist else None
 
-        groups = None
-        if with_hist:
-            hists = np.asarray(hists_dev)
-            groups = group_clients(hists, min(cfg.num_groups, K),
-                                   seed=cfg.seed)
-
-        fw = supervised_weight(r, C=cfg.C, M=self.M,
-                               mode=cfg.supervised_weight_mode)
-        w = agg.combine_weights(
-            [len(self.data["clients"][i]["x"]) for i in part_ids],
-            [stale[i] for i in part_ids], self.g_fn, groups)
+        with tm.span(tm.WEIGHTS):
+            fw = supervised_weight(r, C=cfg.C, M=self.M,
+                                   mode=cfg.supervised_weight_mode)
+            w = agg.combine_weights(
+                [len(self.data["clients"][i]["x"]) for i in part_ids],
+                [stale[i] for i in part_ids], self.g_fn, groups)
 
         self.global_version += 1
         # distribution: latest + deprecated clients get the new model. All
@@ -1327,38 +1355,72 @@ class FedS3ATrainer:
             # chain-delta broadcast: the finalize jit encodes ONE chain
             # transition against R_r; the store books the suffix from the
             # stalest target's version, each transition payload once
-            prev = self.store.latest()
-            if self._csr_wire:
-                out = self._finalize_fn()(
-                    sp_flat, base_flat, payload,
-                    jnp.asarray(w, jnp.float32), jnp.float32(fw), prev)
-            else:
-                out = self._finalize_fn()(
-                    sp_flat, uploaded_flat, jnp.asarray(w, jnp.float32),
-                    jnp.float32(fw), prev)
-            new_flat, recon, chain = out[0], out[1], out[2:]
-            self._advance_versioned(recon, chain, ev, part_ids)
+            with tm.span(tm.FINALIZE):
+                prev = self.store.latest()
+                if self._csr_wire:
+                    out = self._finalize_fn()(
+                        sp_flat, base_flat, payload,
+                        jnp.asarray(w, jnp.float32), jnp.float32(fw), prev)
+                else:
+                    out = self._finalize_fn()(
+                        sp_flat, uploaded_flat, jnp.asarray(w, jnp.float32),
+                        jnp.float32(fw), prev)
+            with tm.span(tm.STORE):
+                new_flat, recon, chain = out[0], out[1], out[2:]
+                self._advance_versioned(recon, chain, ev, part_ids)
         else:
-            targets, _ = self._distribution_plan(part_ids, ev)
-            dist_base = jnp.stack([self._base_rows[i] for i in targets])
-            if self._csr_wire:
-                new_flat, new_base, nnz_d = self._finalize_fn()(
-                    sp_flat, base_flat, payload,
-                    jnp.asarray(w, jnp.float32), jnp.float32(fw), dist_base)
-                self.comm.account_batch_csr(nnz_d, n, len(targets))
-            else:
-                new_flat, new_base, nnz_d = self._finalize_fn()(
-                    sp_flat, uploaded_flat, jnp.asarray(w, jnp.float32),
-                    jnp.float32(fw), dist_base)
-                self.comm.account_batch(nnz_d, n, len(targets))
-            for row, i in enumerate(targets):
-                self._base_rows[i] = new_base[row]
-            self._base_version[targets] = self.global_version
-            self._reset_forced_residuals(forced)
+            with tm.span(tm.FINALIZE):
+                targets, _ = self._distribution_plan(part_ids, ev)
+                dist_base = jnp.stack([self._base_rows[i] for i in targets])
+                if self._csr_wire:
+                    new_flat, new_base, nnz_d = self._finalize_fn()(
+                        sp_flat, base_flat, payload,
+                        jnp.asarray(w, jnp.float32), jnp.float32(fw),
+                        dist_base)
+                    self.comm.account_batch_csr(nnz_d, n, len(targets))
+                else:
+                    new_flat, new_base, nnz_d = self._finalize_fn()(
+                        sp_flat, uploaded_flat, jnp.asarray(w, jnp.float32),
+                        jnp.float32(fw), dist_base)
+                    self.comm.account_batch(nnz_d, n, len(targets))
+            with tm.span(tm.STORE):
+                for row, i in enumerate(targets):
+                    self._base_rows[i] = new_base[row]
+                self._base_version[targets] = self.global_version
+                self._reset_forced_residuals(forced)
         self._global_flat = new_flat
         self._gp_tree = None      # materialized lazily on demand
 
         return self._round_epilogue(prev_time, ev)
+
+    def _count_epoch(self, loss, live, xs):
+        """The round's client-epoch counters: each participant's loss and
+        optimizer steps (device arrays, read later) and the steps each ran
+        (the padded batch count times the epochs)."""
+        tm.count(tm.CLIENT_LIVE_STEPS, live)
+        tm.count(tm.CLIENT_LOSS, loss)
+        tm.count(tm.CLIENT_STEPS_RUN,
+                 xs.shape[1] // self.cfg.batch_size * self.cfg.epochs)
+
+    def _server_epoch_flat(self):
+        """Server supervised epoch on the current global model (Eq. 6), in
+        flat space; the RNG split order matches the sequential path."""
+        with tm.span(tm.SERVER_EPOCH):
+            self.rng, k = jax.random.split(self.rng)
+            sp_flat, self.server_opt, _ = self.server_epoch_flat(
+                self._global_flat, self.server_opt,
+                self.data["server"]["x"], self.data["server"]["y"],
+                self.cfg.lr, k)
+        return sp_flat
+
+    def _group(self, hists_dev, K):
+        """k-means groups of the participants' pseudo-label histograms: the
+        round's one host transfer."""
+        with tm.span(tm.GROUPING):
+            with tm.span(tm.HIST_FETCH, wait=True):
+                hists = np.asarray(hists_dev)
+            return group_clients(hists, min(self.cfg.num_groups, K),
+                                 seed=self.cfg.seed)
 
     # ------------------------------------------------------------------
     # chunked round body (core.param_layout): all engines stream the delta
@@ -1397,21 +1459,24 @@ class FedS3ATrainer:
                 # histograms need the full uploaded model for the forward
                 # pass; build it by scattering each chunk's decode into the
                 # gathered base (one (K, N) buffer, same as training held)
-                up = ring[slots]
-                for p, dec in zip(plan, decoded):
-                    up = up.at[:, p["s"]:p["e"]].add(dec)
-                hists = hist(up, xs, vs)
+                with tm.scope(tm.HISTOGRAM):
+                    up = ring[slots]
+                    for p, dec in zip(plan, decoded):
+                        up = up.at[:, p["s"]:p["e"]].add(dec)
+                    hists = hist(up, xs, vs)
             flat_payload = tuple(x for pay in payloads for x in pay)
             return flat_payload, stored_total, hists, nrv, nri
 
         if ef:
             @jax.jit
             def fn(trained, ring, slots, xs, vs, rvals, ridx):
-                return encode(trained, ring, slots, xs, vs, rvals, ridx)
+                with tm.scope(tm.UPLOAD):
+                    return encode(trained, ring, slots, xs, vs, rvals, ridx)
         else:
             @jax.jit
             def fn(trained, ring, slots, xs, vs):
-                return encode(trained, ring, slots, xs, vs, None, None)
+                with tm.scope(tm.UPLOAD):
+                    return encode(trained, ring, slots, xs, vs, None, None)
 
         self._upload_jits[key] = fn
         return fn
@@ -1433,21 +1498,24 @@ class FedS3ATrainer:
         @jax.jit
         def fn(server_flat, ring, slots, payload, w, fw, prev):
             new = []
-            for ci, p in enumerate(plan):
-                s, e = p["s"], p["e"]
-                pc = payload[ci * arity:(ci + 1) * arity]
-                base_c = ring[:, s:e][slots]
-                if quantized:
-                    new_c = agg.blend_flat_csr_q(
-                        server_flat[s:e], base_c, *pc, w, fw,
-                        use_kernel=False)
-                else:
-                    new_c = agg.blend_flat_csr(
-                        server_flat[s:e], base_c, pc[0], pc[1], w, fw,
-                        use_kernel=False)
-                new.append(new_c)
-            new_flat = jnp.concatenate(new)
-            recon, chain = advance(new_flat, prev)
+            with tm.scope(tm.FINALIZE):
+                with tm.scope(tm.BLEND):
+                    for ci, p in enumerate(plan):
+                        s, e = p["s"], p["e"]
+                        pc = payload[ci * arity:(ci + 1) * arity]
+                        base_c = ring[:, s:e][slots]
+                        if quantized:
+                            new_c = agg.blend_flat_csr_q(
+                                server_flat[s:e], base_c, *pc, w, fw,
+                                use_kernel=False)
+                        else:
+                            new_c = agg.blend_flat_csr(
+                                server_flat[s:e], base_c, pc[0], pc[1], w,
+                                fw, use_kernel=False)
+                        new.append(new_c)
+                    new_flat = jnp.concatenate(new)
+                with tm.scope(tm.DISTRIBUTE):
+                    recon, chain = advance(new_flat, prev)
             return (new_flat, recon) + chain
 
         self._finalize_jit = fn
@@ -1467,8 +1535,7 @@ class FedS3ATrainer:
 
         def shard_fn(ring, slots, xs, vs, lrs, keys):
             base = ring[slots]
-            trained, _ = epoch(base, xs, vs, lrs, keys)
-            return trained
+            return epoch(base, xs, vs, lrs, keys)[0]
 
         fn = jax.jit(shard_map(
             shard_fn, mesh=mesh,
@@ -1496,85 +1563,86 @@ class FedS3ATrainer:
         keys = self._split_keys(K)
 
         if self.engine == "sharded":
-            D = self.mesh.devices.size
-            Kp = padded_rows(K, D)
-            pad = Kp - K
-            pad_ids = part_ids + part_ids[:1] * pad
-            xs, vs = self._gather_data(pad_ids)
-            if pad:
-                keys_p = jnp.concatenate(
-                    [keys, jnp.zeros((pad,) + keys.shape[1:], keys.dtype)])
-                # pad rows see no valid samples -> pure no-op epochs
-                vs = vs * jnp.asarray(
-                    np.concatenate([np.ones(K, np.float32),
-                                    np.zeros(pad, np.float32)]))[:, None]
-            else:
-                keys_p = keys
-            lrs_p = jnp.asarray(
-                np.concatenate([lrs[part_ids], np.zeros(pad)]), jnp.float32)
-            slots_p = self.store.slots_for(pad_ids)
-            trained = self._train_sharded_chunked()(
-                self.store.ring, slots_p, xs, vs, lrs_p, keys_p)
-            trained = trained[:K]
-            xs, vs = xs[:K], vs[:K]
-            slots = slots_p[:K]
+            with tm.span(tm.GATHER):
+                D = self.mesh.devices.size
+                Kp = padded_rows(K, D)
+                pad = Kp - K
+                pad_ids = part_ids + part_ids[:1] * pad
+                xs, vs = self._gather_data(pad_ids)
+                if pad:
+                    keys_p = jnp.concatenate(
+                        [keys, jnp.zeros((pad,) + keys.shape[1:],
+                                         keys.dtype)])
+                    # pad rows see no valid samples -> pure no-op epochs
+                    vs = vs * jnp.asarray(
+                        np.concatenate([np.ones(K, np.float32),
+                                        np.zeros(pad, np.float32)]))[:, None]
+                else:
+                    keys_p = keys
+                lrs_p = jnp.asarray(
+                    np.concatenate([lrs[part_ids], np.zeros(pad)]),
+                    jnp.float32)
+                slots_p = self.store.slots_for(pad_ids)
+            with tm.span(tm.CLIENT_EPOCH):
+                trained = self._train_sharded_chunked()(
+                    self.store.ring, slots_p, xs, vs, lrs_p, keys_p)
+                trained = trained[:K]
+                xs, vs = xs[:K], vs[:K]
+                slots = slots_p[:K]
         else:
-            xs, vs = self._gather_data(part_ids)
-            slots = self.store.slots_for(part_ids)
-            base_flat = self.store.gather(part_ids)
-            trained, _ = self.batched_epoch(base_flat, xs, vs,
-                                            lrs[part_ids], keys)
+            with tm.span(tm.GATHER):
+                xs, vs = self._gather_data(part_ids)
+                slots = self.store.slots_for(part_ids)
+                base_flat = self.store.gather(part_ids)
+            with tm.span(tm.CLIENT_EPOCH):
+                trained, loss, live = self.batched_epoch(
+                    base_flat, xs, vs, lrs[part_ids], keys)
+                self._count_epoch(loss, live, xs)
 
         with_hist = cfg.group_based and K > 1
-        upload = self._chunk_upload_fn(with_hist)
-        if cfg.error_feedback:
-            if self.paged:
-                rv, rx = self.cstore.gather_csr(part_ids)
+        with tm.span(tm.UPLOAD):
+            upload = self._chunk_upload_fn(with_hist)
+            if cfg.error_feedback:
+                if self.paged:
+                    rv, rx = self.cstore.gather_csr(part_ids)
+                else:
+                    idxK = jnp.asarray(part_ids)
+                    rv = _gather_rows(self._res_vals, idxK)
+                    rx = _gather_rows(self._res_idx, idxK)
+                payload, stored_total, hists_dev, nrv, nri = upload(
+                    trained, self.store.ring, slots, xs, vs, rv, rx)
+                if self.paged:
+                    self.cstore.scatter_csr(part_ids, nrv, nri)
+                else:
+                    self._res_vals = _scatter_rows(self._res_vals, idxK, nrv)
+                    self._res_idx = _scatter_rows(self._res_idx, idxK, nri)
             else:
-                idxK = jnp.asarray(part_ids)
-                rv = _gather_rows(self._res_vals, idxK)
-                rx = _gather_rows(self._res_idx, idxK)
-            payload, stored_total, hists_dev, nrv, nri = upload(
-                trained, self.store.ring, slots, xs, vs, rv, rx)
-            if self.paged:
-                self.cstore.scatter_csr(part_ids, nrv, nri)
-            else:
-                self._res_vals = _scatter_rows(self._res_vals, idxK, nrv)
-                self._res_idx = _scatter_rows(self._res_idx, idxK, nri)
-        else:
-            payload, stored_total, hists_dev, _, _ = upload(
-                trained, self.store.ring, slots, xs, vs)
-        # one ledger entry for the whole chunked batch; the layout-aware
-        # framing (per-chunk row_ptr, scales, block tables) is booked by
-        # the comm channel's chunk-aware accounting
-        self.comm.account_batch_csr(stored_total, n, K)
+                payload, stored_total, hists_dev, _, _ = upload(
+                    trained, self.store.ring, slots, xs, vs)
+            # one ledger entry for the whole chunked batch; the layout-aware
+            # framing (per-chunk row_ptr, scales, block tables) is booked by
+            # the comm channel's chunk-aware accounting
+            self.comm.account_batch_csr(stored_total, n, K)
 
-        # server supervised epoch on the current global model (Eq. 6), in
-        # flat space; the RNG split order matches the flat engines
-        self.rng, k = jax.random.split(self.rng)
-        sp_flat, self.server_opt, _ = self.server_epoch_flat(
-            self._global_flat, self.server_opt,
-            self.data["server"]["x"], self.data["server"]["y"], cfg.lr, k)
+        sp_flat = self._server_epoch_flat()
+        groups = self._group(hists_dev, K) if with_hist else None
 
-        groups = None
-        if with_hist:
-            hists = np.asarray(hists_dev)
-            groups = group_clients(hists, min(cfg.num_groups, K),
-                                   seed=cfg.seed)
-
-        fw = supervised_weight(r, C=cfg.C, M=self.M,
-                               mode=cfg.supervised_weight_mode)
-        w = agg.combine_weights(
-            [len(self.data["clients"][i]["x"]) for i in part_ids],
-            [stale[i] for i in part_ids], self.g_fn, groups)
+        with tm.span(tm.WEIGHTS):
+            fw = supervised_weight(r, C=cfg.C, M=self.M,
+                                   mode=cfg.supervised_weight_mode)
+            w = agg.combine_weights(
+                [len(self.data["clients"][i]["x"]) for i in part_ids],
+                [stale[i] for i in part_ids], self.g_fn, groups)
 
         self.global_version += 1
-        prev = self.store.latest()
-        out = self._chunk_finalize_fn()(
-            sp_flat, self.store.ring, slots, payload,
-            jnp.asarray(w, jnp.float32), jnp.float32(fw), prev)
-        new_flat, recon, chain = out[0], out[1], out[2:]
-        self._advance_versioned(recon, chain, ev, part_ids)
+        with tm.span(tm.FINALIZE):
+            prev = self.store.latest()
+            out = self._chunk_finalize_fn()(
+                sp_flat, self.store.ring, slots, payload,
+                jnp.asarray(w, jnp.float32), jnp.float32(fw), prev)
+        with tm.span(tm.STORE):
+            new_flat, recon, chain = out[0], out[1], out[2:]
+            self._advance_versioned(recon, chain, ev, part_ids)
         self._global_flat = new_flat
         self._gp_tree = None      # materialized lazily on demand
 
@@ -1640,7 +1708,7 @@ class FedS3ATrainer:
                     xs, vs, lrs, keys, rvals, ridx = args[2:]
                 else:
                     base, xs, vs, lrs, keys, rvals, ridx = args
-                trained, _ = epoch(base, xs, vs, lrs, keys)
+                trained = epoch(base, xs, vs, lrs, keys)[0]
                 # the residual store arrives as CSR rows; expand the local
                 # shard to dense only inside the stage (per-row scatter)
                 residual = csr_decode(rvals, ridx, n) if with_residual \
@@ -1672,7 +1740,7 @@ class FedS3ATrainer:
                 xs, vs, lrs, keys, residual = args[2:]
             else:
                 base, xs, vs, lrs, keys, residual = args
-            trained, _ = epoch(base, xs, vs, lrs, keys)
+            trained = epoch(base, xs, vs, lrs, keys)[0]
             uploaded, nnz, hists, new_res = encode_upload(
                 trained, base, xs, vs, residual if with_residual else None)
             return (uploaded, nnz,
@@ -1703,10 +1771,12 @@ class FedS3ATrainer:
 
         @jax.jit
         def fn(hists, size_g):
-            assign, _ = kmeans_device(hists[:K], num_groups,
-                                      init_idx=init_idx)
-            w = agg.combine_weights_device(size_g, assign, num_groups)
-            return jnp.zeros((Kp,), jnp.float32).at[:K].set(w)
+            with tm.scope(tm.GROUPING):
+                assign, _ = kmeans_device(hists[:K], num_groups,
+                                          init_idx=init_idx)
+            with tm.scope(tm.WEIGHTS):
+                w = agg.combine_weights_device(size_g, assign, num_groups)
+                return jnp.zeros((Kp,), jnp.float32).at[:K].set(w)
 
         self._groupw_jits[key] = fn
         return fn
@@ -1747,9 +1817,12 @@ class FedS3ATrainer:
             if versioned:
                 def shard_fn(server_flat, ring, slots, payload, w, fw,
                              prev):
-                    base = ring[slots]
-                    new_flat = blend(server_flat, base, payload, w, fw)
-                    recon, chain = distribute(new_flat, prev)
+                    with tm.scope(tm.FINALIZE):
+                        with tm.scope(tm.BLEND):
+                            base = ring[slots]
+                            new_flat = blend(server_flat, base, payload, w,
+                                             fw)
+                        recon, chain = distribute(new_flat, prev)
                     return (new_flat, recon) + chain
 
                 fn = jax.jit(shard_map(
@@ -1762,8 +1835,10 @@ class FedS3ATrainer:
                 return fn
 
             def shard_fn(server_flat, base, payload, w, fw, dist_base):
-                new_flat = blend(server_flat, base, payload, w, fw)
-                new_base, nnz = distribute(new_flat, dist_base)
+                with tm.scope(tm.FINALIZE):
+                    with tm.scope(tm.BLEND):
+                        new_flat = blend(server_flat, base, payload, w, fw)
+                    new_base, nnz = distribute(new_flat, dist_base)
                 return new_flat, new_base, nnz
 
             fn = jax.jit(shard_map(
@@ -1775,10 +1850,12 @@ class FedS3ATrainer:
 
         if versioned:
             def shard_fn(server_flat, uploaded, w, fw, prev):
-                new_flat = agg.blend_flat_sharded(
-                    server_flat, uploaded, w, fw,
-                    axis_name=CLIENT_AXIS, use_kernel=use_kernel)
-                recon, payload = distribute(new_flat, prev)
+                with tm.scope(tm.FINALIZE):
+                    with tm.scope(tm.BLEND):
+                        new_flat = agg.blend_flat_sharded(
+                            server_flat, uploaded, w, fw,
+                            axis_name=CLIENT_AXIS, use_kernel=use_kernel)
+                    recon, payload = distribute(new_flat, prev)
                 return (new_flat, recon) + payload
 
             fn = jax.jit(shard_map(
@@ -1790,10 +1867,12 @@ class FedS3ATrainer:
             return fn
 
         def shard_fn(server_flat, uploaded, w, fw, dist_base):
-            new_flat = agg.blend_flat_sharded(
-                server_flat, uploaded, w, fw,
-                axis_name=CLIENT_AXIS, use_kernel=use_kernel)
-            new_base, nnz = distribute(new_flat, dist_base)
+            with tm.scope(tm.FINALIZE):
+                with tm.scope(tm.BLEND):
+                    new_flat = agg.blend_flat_sharded(
+                        server_flat, uploaded, w, fw,
+                        axis_name=CLIENT_AXIS, use_kernel=use_kernel)
+                new_base, nnz = distribute(new_flat, dist_base)
             return new_flat, new_base, nnz
 
         fn = jax.jit(shard_map(
@@ -1823,95 +1902,98 @@ class FedS3ATrainer:
         # same RNG stream as the sequential path: one split per REAL
         # participant in arrival order, then the server's split
         keys = self._split_keys(K)
-        pad_ids = part_ids + part_ids[:1] * pad
-        idx = jnp.asarray(pad_ids)
-        xs, vs = self._gather_data(pad_ids)
-        if pad:
-            keys = jnp.concatenate([keys, jnp.zeros((pad,) + keys.shape[1:],
-                                                    keys.dtype)])
-            # pad rows see no valid samples -> their epoch is a pure no-op
-            vs = vs * jnp.asarray(
-                np.concatenate([np.ones(K, np.float32),
-                                np.zeros(pad, np.float32)]))[:, None]
-        lrs_p = jnp.asarray(np.concatenate([lrs[part_ids], np.zeros(pad)]),
-                            jnp.float32)
-        if self.base_store == "versioned":
-            # the base rows are gathered from the replicated (tau+2, N)
-            # ring inside the stages; only the slot vector crosses in
-            slots = self.store.slots_for(pad_ids)
-            base_args = (self.store.ring, slots)
-        else:
-            base_args = (_gather_rows(self._base_mat, idx),)
+        with tm.span(tm.GATHER):
+            pad_ids = part_ids + part_ids[:1] * pad
+            idx = jnp.asarray(pad_ids)
+            xs, vs = self._gather_data(pad_ids)
+            if pad:
+                keys = jnp.concatenate(
+                    [keys, jnp.zeros((pad,) + keys.shape[1:], keys.dtype)])
+                # pad rows see no valid samples -> their epoch is a pure
+                # no-op
+                vs = vs * jnp.asarray(
+                    np.concatenate([np.ones(K, np.float32),
+                                    np.zeros(pad, np.float32)]))[:, None]
+            lrs_p = jnp.asarray(
+                np.concatenate([lrs[part_ids], np.zeros(pad)]), jnp.float32)
+            if self.base_store == "versioned":
+                # the base rows are gathered from the replicated (tau+2, N)
+                # ring inside the stages; only the slot vector crosses in
+                slots = self.store.slots_for(pad_ids)
+                base_args = (self.store.ring, slots)
+            else:
+                base_args = (_gather_rows(self._base_mat, idx),)
         n = self._global_flat.shape[0]
 
         with_hist = cfg.group_based and K > 1
-        stage1 = self._stage1_sharded(cfg.error_feedback, with_hist)
-        if self._csr_wire:
-            arity = self._payload_arity
-            if cfg.error_feedback:
-                # residual rows travel as CSR (values, indices) — the dense
-                # (M, N) residual matrix no longer exists. Paged store: the
-                # (Kp, rcap) window comes off the host pages instead of a
-                # device (M, rcap) gather; the stage is unchanged (it
-                # already consumes participant windows)
-                if self.paged:
-                    rvals, ridx = self.cstore.gather_csr(pad_ids)
+        # one stage trains and encodes: the client epoch runs inside the
+        # upload span (its device scope still names it)
+        with tm.span(tm.UPLOAD):
+            stage1 = self._stage1_sharded(cfg.error_feedback, with_hist)
+            if self._csr_wire:
+                arity = self._payload_arity
+                if cfg.error_feedback:
+                    # residual rows travel as CSR (values, indices) — the
+                    # dense (M, N) residual matrix no longer exists. Paged
+                    # store: the (Kp, rcap) window comes off the host pages
+                    # instead of a device (M, rcap) gather; the stage is
+                    # unchanged (it already consumes participant windows)
+                    if self.paged:
+                        rvals, ridx = self.cstore.gather_csr(pad_ids)
+                    else:
+                        rvals = _gather_rows(self._res_vals, idx)
+                        ridx = _gather_rows(self._res_idx, idx)
+                    out = stage1(*base_args, xs, vs, lrs_p, keys, rvals,
+                                 ridx)
+                    nrv, nri = out[arity + 2], out[arity + 3]
+                    if self.paged:
+                        self.cstore.scatter_csr(part_ids, nrv[:K], nri[:K])
+                    else:
+                        self._res_vals = _scatter_rows(
+                            self._res_vals, idx[:K], nrv[:K])
+                        self._res_idx = _scatter_rows(
+                            self._res_idx, idx[:K], nri[:K])
                 else:
-                    rvals = _gather_rows(self._res_vals, idx)
-                    ridx = _gather_rows(self._res_idx, idx)
-                out = stage1(*base_args, xs, vs, lrs_p, keys, rvals, ridx)
-                nrv, nri = out[arity + 2], out[arity + 3]
+                    z = jnp.zeros((), jnp.float32)
+                    out = stage1(*base_args, xs, vs, lrs_p, keys, z, z)
+                payload, nnz, hists_dev = \
+                    tuple(out[:arity]), out[arity], out[arity + 1]
+                self.comm.account_batch_csr(nnz[:K], n, K)
+            elif cfg.error_feedback:
+                residual = self.cstore.gather_dense(pad_ids) if self.paged \
+                    else _gather_rows(self._residual_mat, idx)
+                uploaded, nnz, hists_dev, new_res = stage1(
+                    *base_args, xs, vs, lrs_p, keys, residual)
                 if self.paged:
-                    self.cstore.scatter_csr(part_ids, nrv[:K], nri[:K])
+                    self.cstore.scatter_dense(part_ids, new_res[:K])
                 else:
-                    self._res_vals = _scatter_rows(self._res_vals, idx[:K],
-                                                   nrv[:K])
-                    self._res_idx = _scatter_rows(self._res_idx, idx[:K],
-                                                  nri[:K])
+                    self._residual_mat = _scatter_rows(
+                        self._residual_mat, idx[:K], new_res[:K])
+                self.comm.account_batch(nnz[:K], n, K)
             else:
-                z = jnp.zeros((), jnp.float32)
-                out = stage1(*base_args, xs, vs, lrs_p, keys, z, z)
-            payload, nnz, hists_dev = \
-                tuple(out[:arity]), out[arity], out[arity + 1]
-            self.comm.account_batch_csr(nnz[:K], n, K)
-        elif cfg.error_feedback:
-            residual = self.cstore.gather_dense(pad_ids) if self.paged \
-                else _gather_rows(self._residual_mat, idx)
-            uploaded, nnz, hists_dev, new_res = stage1(
-                *base_args, xs, vs, lrs_p, keys, residual)
-            if self.paged:
-                self.cstore.scatter_dense(part_ids, new_res[:K])
+                uploaded, nnz, hists_dev, _ = stage1(
+                    *base_args, xs, vs, lrs_p, keys,
+                    jnp.zeros((), jnp.float32))
+                self.comm.account_batch(nnz[:K], n, K)
+
+        sp_flat = self._server_epoch_flat()
+
+        with tm.span(tm.WEIGHTS):
+            sizes = [len(self.data["clients"][i]["x"]) for i in part_ids]
+            stales = [stale[i] for i in part_ids]
+            if with_hist:
+                size_g = np.asarray(sizes, np.float64) * \
+                    np.array([self.g_fn(s) for s in stales])
+                w_pad = self._group_weights_sharded(
+                    K, min(cfg.num_groups, K), Kp)(
+                        hists_dev, jnp.asarray(size_g, jnp.float32))
             else:
-                self._residual_mat = _scatter_rows(
-                    self._residual_mat, idx[:K], new_res[:K])
-            self.comm.account_batch(nnz[:K], n, K)
-        else:
-            uploaded, nnz, hists_dev, _ = stage1(
-                *base_args, xs, vs, lrs_p, keys, jnp.zeros((), jnp.float32))
-            self.comm.account_batch(nnz[:K], n, K)
+                w = agg.combine_weights(sizes, stales, self.g_fn, None)
+                w_pad = jnp.asarray(np.concatenate([w, np.zeros(pad)]),
+                                    jnp.float32)
+            fw = supervised_weight(r, C=cfg.C, M=self.M,
+                                   mode=cfg.supervised_weight_mode)
 
-        # server supervised epoch on the current global model (Eq. 6), in
-        # flat space; the RNG split order matches the sequential path
-        self.rng, k = jax.random.split(self.rng)
-        sp_flat, self.server_opt, _ = self.server_epoch_flat(
-            self._global_flat, self.server_opt,
-            self.data["server"]["x"], self.data["server"]["y"], cfg.lr, k)
-
-        sizes = [len(self.data["clients"][i]["x"]) for i in part_ids]
-        stales = [stale[i] for i in part_ids]
-        if with_hist:
-            size_g = np.asarray(sizes, np.float64) * \
-                np.array([self.g_fn(s) for s in stales])
-            w_pad = self._group_weights_sharded(
-                K, min(cfg.num_groups, K), Kp)(
-                    hists_dev, jnp.asarray(size_g, jnp.float32))
-        else:
-            w = agg.combine_weights(sizes, stales, self.g_fn, None)
-            w_pad = jnp.asarray(np.concatenate([w, np.zeros(pad)]),
-                                jnp.float32)
-
-        fw = supervised_weight(r, C=cfg.C, M=self.M,
-                               mode=cfg.supervised_weight_mode)
         self.global_version += 1
         # distribution: latest + deprecated clients get the new model
         if self.base_store == "versioned":
@@ -1919,35 +2001,40 @@ class FedS3ATrainer:
             # in the stage; the store books the suffix from the stalest
             # target's version (each transition payload once) — no
             # per-target rows, gathers or retraces on the target count
-            prev = self.store.latest()
-            if self._csr_wire:
-                out = self._stage2_sharded()(
-                    sp_flat, self.store.ring, slots, payload, w_pad,
-                    jnp.float32(fw), prev)
-            else:
-                out = self._stage2_sharded()(
-                    sp_flat, uploaded, w_pad, jnp.float32(fw), prev)
-            new_flat, recon, chain = out[0], out[1], out[2:]
-            self._advance_versioned(recon, chain, ev, part_ids)
+            with tm.span(tm.FINALIZE):
+                prev = self.store.latest()
+                if self._csr_wire:
+                    out = self._stage2_sharded()(
+                        sp_flat, self.store.ring, slots, payload, w_pad,
+                        jnp.float32(fw), prev)
+                else:
+                    out = self._stage2_sharded()(
+                        sp_flat, uploaded, w_pad, jnp.float32(fw), prev)
+            with tm.span(tm.STORE):
+                new_flat, recon, chain = out[0], out[1], out[2:]
+                self._advance_versioned(recon, chain, ev, part_ids)
         else:
-            targets, _ = self._distribution_plan(part_ids, ev)
-            T = len(targets)
-            Tp = padded_rows(T, D)
-            tidx = jnp.asarray(targets + targets[:1] * (Tp - T))
-            dist_base = _gather_rows(self._base_mat, tidx)
-            if self._csr_wire:
-                new_flat, new_base, nnz_d = self._stage2_sharded()(
-                    sp_flat, base_args[0], payload, w_pad,
-                    jnp.float32(fw), dist_base)
-                self.comm.account_batch_csr(nnz_d[:T], n, T)
-            else:
-                new_flat, new_base, nnz_d = self._stage2_sharded()(
-                    sp_flat, uploaded, w_pad, jnp.float32(fw), dist_base)
-                self.comm.account_batch(nnz_d[:T], n, T)
-            self._base_mat = _scatter_rows(self._base_mat, tidx[:T],
-                                           new_base[:T])
-            self._base_version[targets] = self.global_version
-            self._reset_forced_residuals(forced)
+            with tm.span(tm.FINALIZE):
+                targets, _ = self._distribution_plan(part_ids, ev)
+                T = len(targets)
+                Tp = padded_rows(T, D)
+                tidx = jnp.asarray(targets + targets[:1] * (Tp - T))
+                dist_base = _gather_rows(self._base_mat, tidx)
+                if self._csr_wire:
+                    new_flat, new_base, nnz_d = self._stage2_sharded()(
+                        sp_flat, base_args[0], payload, w_pad,
+                        jnp.float32(fw), dist_base)
+                    self.comm.account_batch_csr(nnz_d[:T], n, T)
+                else:
+                    new_flat, new_base, nnz_d = self._stage2_sharded()(
+                        sp_flat, uploaded, w_pad, jnp.float32(fw),
+                        dist_base)
+                    self.comm.account_batch(nnz_d[:T], n, T)
+            with tm.span(tm.STORE):
+                self._base_mat = _scatter_rows(self._base_mat, tidx[:T],
+                                               new_base[:T])
+                self._base_version[targets] = self.global_version
+                self._reset_forced_residuals(forced)
         self._global_flat = new_flat
         self._gp_tree = None      # materialized lazily on demand
 

@@ -37,6 +37,7 @@ from repro.core.pseudo_label import (_cnn_template, class_histogram,
                                      make_batched_client_epoch,
                                      make_client_epoch, make_server_epoch,
                                      make_server_epoch_flat, predict_fn)
+from repro.core.telemetry import CLIENT_EPOCH, SERVER_EPOCH, scope
 from repro.kernels import ops as kops
 from repro.kernels.ref import masked_pseudo_ce_ref
 from repro.models.cnn import cnn_param_count, init_cnn
@@ -196,8 +197,9 @@ def _lm_suite(cfg, batch_size, threshold, l1, use_kernel, epochs):
             state, opt = adam_update(g, opt, state, lr=lr, l1=l1)
             return (state, opt, rng), l
 
-        (state, opt, _), losses = jax.lax.scan(step, (state, opt, rng),
-                                               (xb, yb, vb))
+        with scope(SERVER_EPOCH):
+            (state, opt, _), losses = jax.lax.scan(step, (state, opt, rng),
+                                                   (xb, yb, vb))
         return state, opt, jnp.mean(losses)
 
     def _server_run(state, opt, x_np, y_np, lr, rng, flat_state):
@@ -244,11 +246,14 @@ def _lm_suite(cfg, batch_size, threshold, l1, use_kernel, epochs):
                 flat, o, l = jax.lax.cond(live, live_step, dead_step, None)
                 return (flat, o, rng), (l, live)
 
+            live_steps = jnp.int32(0)
             for e in range(epochs):
                 ek = rng if e == 0 else jax.random.fold_in(rng, e)
                 (flat, opt, _), (losses, lives) = jax.lax.scan(
                     step, (flat, opt, ek), (xb, vb))
-            return flat, jnp.sum(losses) / jnp.maximum(jnp.sum(lives), 1.0)
+                live_steps = live_steps + jnp.sum(lives, dtype=jnp.int32)
+            return flat, jnp.sum(losses) / jnp.maximum(jnp.sum(lives), 1.0), \
+                live_steps
 
         if jax.default_backend() == "cpu":
             def all_clients(*args):
@@ -257,7 +262,8 @@ def _lm_suite(cfg, batch_size, threshold, l1, use_kernel, epochs):
             def all_clients(*args):
                 return jax.vmap(one_client)(*args)
 
-        return all_clients(base_flat, x, valid, lrs, rngs)
+        with scope(CLIENT_EPOCH):
+            return all_clients(base_flat, x, valid, lrs, rngs)
 
     def batched_epoch(base_flat, x, valid, lrs, rngs):
         nb = x.shape[1] // batch_size

@@ -8,6 +8,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import CLIENT_EPOCH, SERVER_EPOCH, scope
 from repro.kernels import ops as kops
 from repro.models.cnn import cnn_forward
 from repro.optimizer import adam_update
@@ -163,11 +164,14 @@ def make_batched_client_epoch(cfg, *, batch_size=100, threshold=0.95, l1=0.0,
             # the same fold, keeping the engines pinned at epochs > 1 — the
             # old restart-from-the-same-key form replayed identical masks
             # every epoch in BOTH paths.
+            live_steps = jnp.int32(0)
             for e in range(epochs):
                 ek = rng if e == 0 else jax.random.fold_in(rng, e)
                 (flat, opt, _), (losses, lives) = jax.lax.scan(
                     step, (flat, opt, ek), (xb, vb))
-            return flat, jnp.sum(losses) / jnp.maximum(jnp.sum(lives), 1.0)
+                live_steps = live_steps + jnp.sum(lives, dtype=jnp.int32)
+            return flat, jnp.sum(losses) / jnp.maximum(jnp.sum(lives), 1.0), \
+                live_steps
 
         # Client-axis strategy: vmap on accelerators; on XLA:CPU batched
         # GEMMs degrade superlinearly past K~4 (measured 2x at K=6), so we
@@ -180,10 +184,13 @@ def make_batched_client_epoch(cfg, *, batch_size=100, threshold=0.95, l1=0.0,
             def all_clients(*args):
                 return jax.vmap(one_client)(*args)
 
-        return all_clients(base_flat, x, valid, lrs, rngs)
+        with scope(CLIENT_EPOCH):
+            return all_clients(base_flat, x, valid, lrs, rngs)
 
     def run(base_flat, x, valid, lrs, rngs):
-        """base_flat: (K, N); x: (K, nb*B, F); valid: (K, nb*B)."""
+        """base_flat: (K, N); x: (K, nb*B, F); valid: (K, nb*B). Returns
+        the trained (K, N) stack, each participant's mean loss of its last
+        epoch and the optimizer steps it took over all epochs (K,)."""
         nb = x.shape[1] // batch_size
         return epoch(base_flat, x, valid,
                      jnp.asarray(lrs, jnp.float32), rngs, nb)
@@ -271,8 +278,9 @@ def make_server_epoch_flat(cfg, *, batch_size=100, l1=0.0):
             flat, opt = adam_update(g, opt, flat, lr=lr, l1=l1)
             return (flat, opt, rng), l
 
-        (flat, opt, _), losses = jax.lax.scan(step, (flat, opt, rng),
-                                              (xb, yb, vb))
+        with scope(SERVER_EPOCH):
+            (flat, opt, _), losses = jax.lax.scan(step, (flat, opt, rng),
+                                                  (xb, yb, vb))
         return flat, opt, jnp.mean(losses)
 
     def run(flat, opt, x_np, y_np, lr, rng):

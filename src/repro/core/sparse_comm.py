@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import (COMPACT, MASK, QUANTIZE, RESIDUAL,
+                                  THRESHOLD, scope)
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.sparse_delta import local_quantile_thresholds
@@ -396,13 +398,18 @@ class SparseComm:
         capped = kref.csr_capped_mask_ref
 
         def encode_payload(delta, n):
-            thr = row_thr(delta)
-            vals, idx, _ = compact(delta, thr, pay_cap(n))
-            dense, stored = capped(delta, thr, pay_cap(n))
+            with scope(THRESHOLD):
+                thr = row_thr(delta)
+            with scope(COMPACT):
+                vals, idx, _ = compact(delta, thr, pay_cap(n))
+            with scope(MASK):
+                dense, stored = capped(delta, thr, pay_cap(n))
             if not quantized:
                 return (vals, idx), stored, dense
-            qvals, offs, counts, scales = quantize(vals, idx, stored, n)
-            decoded = kref.quantize_dense_ref(dense, scales, q_dtype=q_dtype)
+            with scope(QUANTIZE):
+                qvals, offs, counts, scales = quantize(vals, idx, stored, n)
+                decoded = kref.quantize_dense_ref(dense, scales,
+                                                  q_dtype=q_dtype)
             return (qvals, offs, counts, scales), stored, decoded
 
         if with_residual:
@@ -413,9 +420,13 @@ class SparseComm:
                 payload, stored, decoded = encode_payload(delta, n)
                 res = delta - decoded   # sub-threshold + overflow (+ csr_q
                                         # quantization error: EF absorption)
-                r_thr = local_quantile_thresholds(res, residual_frac)
-                rvals, ridx, _ = compact(res, r_thr, res_cap(n))
-                res_dense, rstored = capped(res, r_thr, res_cap(n))
+                with scope(RESIDUAL):
+                    with scope(THRESHOLD):
+                        r_thr = local_quantile_thresholds(res, residual_frac)
+                    with scope(COMPACT):
+                        rvals, ridx, _ = compact(res, r_thr, res_cap(n))
+                    with scope(MASK):
+                        res_dense, rstored = capped(res, r_thr, res_cap(n))
                 return (payload, stored, decoded,
                         (rvals, ridx, rstored), res_dense)
         else:
@@ -490,15 +501,20 @@ class SparseComm:
         its own jit, where the elementwise/cumsum oracles compile to the
         same fused loops the Pallas grids hand-build at flat N."""
         nc, cap = plan_c["nc"], plan_c["cap"]
-        thr = self._chunk_thresholds(delta_c, plan_c["keep"])
-        vals, idx, _ = kref.csr_compact2d_ref(delta_c, thr, cap)
-        dense, stored = kref.csr_capped_mask_ref(delta_c, thr, cap)
+        with scope(THRESHOLD):
+            thr = self._chunk_thresholds(delta_c, plan_c["keep"])
+        with scope(COMPACT):
+            vals, idx, _ = kref.csr_compact2d_ref(delta_c, thr, cap)
+        with scope(MASK):
+            dense, stored = kref.csr_capped_mask_ref(delta_c, thr, cap)
         if self.wire_format != "csr_q":
             return (vals, idx), stored, dense
-        qvals, scales = kref.csr_quantize2d_ref(vals, stored,
-                                                q_dtype=self.q_dtype)
-        offs, counts = kref.csr_pack_indices_ref(idx, stored, nc)
-        decoded = kref.quantize_dense_ref(dense, scales, q_dtype=self.q_dtype)
+        with scope(QUANTIZE):
+            qvals, scales = kref.csr_quantize2d_ref(vals, stored,
+                                                    q_dtype=self.q_dtype)
+            offs, counts = kref.csr_pack_indices_ref(idx, stored, nc)
+            decoded = kref.quantize_dense_ref(dense, scales,
+                                              q_dtype=self.q_dtype)
         return (qvals, offs, counts, scales), stored, decoded
 
     def chunk_encode_body(self, with_residual=False):
@@ -557,8 +573,13 @@ class SparseComm:
                 pay, st, dec = self._chunk_encode_one(delta_c, p)
                 res_new = delta_c - dec     # sub-threshold + overflow
                                             # (+ csr_q rounding error)
-                r_thr = local_quantile_thresholds(res_new, p["rfrac"])
-                rv, ri, _ = kref.csr_compact2d_ref(res_new, r_thr, rcap)
+                with scope(RESIDUAL):
+                    with scope(THRESHOLD):
+                        r_thr = local_quantile_thresholds(res_new,
+                                                          p["rfrac"])
+                    with scope(COMPACT):
+                        rv, ri, _ = kref.csr_compact2d_ref(res_new, r_thr,
+                                                           rcap)
                 payloads.append(pay)
                 stored.append(st)
                 decoded.append(dec)

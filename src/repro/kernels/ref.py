@@ -220,9 +220,11 @@ def csr_pack_indices_ref(indices, stored, n):
     blk_id = jnp.where(valid, indices // blk, nblk)   # pad -> past the end
     # blk_id ascends along each row, so the number of slots in blocks <= b
     # is a binary search: counts are the differences of that cumulative
-    # histogram, O(nblk log cap) per row
-    upto = jax.vmap(lambda r: jnp.searchsorted(
-        r, jnp.arange(nblk, dtype=jnp.int32), side="right"))(blk_id)
+    # histogram, O(nblk log cap) per row. The scope is the wire's slot-search
+    # leaf (core/telemetry.py COMPACT), on the ref and Pallas paths alike.
+    with jax.named_scope("compact"):
+        upto = jax.vmap(lambda r: jnp.searchsorted(
+            r, jnp.arange(nblk, dtype=jnp.int32), side="right"))(blk_id)
     counts = jnp.diff(upto, axis=1, prepend=0)
     return offs, counts.astype(jnp.int16)
 
