@@ -18,6 +18,7 @@ import numpy as np
 from repro.core.sparse_comm import flatten_tree, unflatten_like
 from repro.core.telemetry import UNPACK, scope
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 
 
 def _weighted_sum_trees(trees, weights, *, use_kernel=False):
@@ -181,25 +182,17 @@ def csr_q_weighted_scatter(qvals, qoffs, qcnt, scales, w, n):
     scales (all-ones for fp16 payloads); w: (K,) combined Eq. 9/10 weights.
 
     Absolute columns are reconstructed exactly as a receiver would —
-    block id per slot via a vmapped binary search over the cumulative
-    block counts (ref.csr_unpack_indices_ref inlined so the whole decode
-    jits into the blend), then ``block * 512 + offset`` — and
+    block id per slot by marks and a running sum over the cumulative
+    block counts (``ref.csr_unpack_indices_ref``, jitted into the blend),
+    then ``block * 512 + offset`` — and
     dequantization FUSES into the weight multiply: the contribution of row
     k is ``(w_k * scale_k) * qvals_k``, so the f32 payload is never
     materialized. Padding slots carry value 0 at a clamped index and
     scatter nothing. Returns sum_k w_k * dequant(decode(payload_k)) as an
     (n,) fp32 vector via one flat scatter-add.
     """
-    K, cap = qoffs.shape
-    nblk = qcnt.shape[1]
     with scope(UNPACK):
-        cum = jnp.cumsum(qcnt.astype(jnp.int32), axis=1)
-        slots = jnp.arange(cap, dtype=jnp.int32)
-        blk = jax.vmap(
-            lambda c: jnp.searchsorted(c, slots, side="right"))(cum)
-        idx = jnp.minimum(blk, nblk - 1).astype(jnp.int32) * 512 + \
-            qoffs.astype(jnp.int32)
-        idx = jnp.minimum(idx, n - 1)
+        idx = jnp.minimum(kref.csr_unpack_indices_ref(qoffs, qcnt), n - 1)
     contrib = (w.astype(jnp.float32) *
                scales.astype(jnp.float32))[:, None] * \
         qvals.astype(jnp.float32)

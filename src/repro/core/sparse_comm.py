@@ -390,7 +390,7 @@ class SparseComm:
         # dense reconstructions use the scatter-free capped-mask twin of the
         # compact->decode round-trip (identical output; XLA:CPU scatters are
         # serial, and on paths that only read the stored counts the
-        # compaction sort dead-code-eliminates entirely). Under csr_q the
+        # compaction dead-code-eliminates entirely). Under csr_q the
         # twin extends through quantization: the absmax over the packed
         # prefix equals the absmax over the capped-mask rows, so the
         # elementwise quantize->dequantize round-trip of the dense rows is

@@ -56,7 +56,7 @@ EPILOGUE = "epilogue"
 # device scopes below the stage names
 HISTOGRAM = "histogram"
 THRESHOLD = "threshold"
-COMPACT = "compact"          # every slot search of the wire encode ...
+COMPACT = "compact"          # every slot placement of the wire encode ...
 UNPACK = "unpack"            # ... and of the csr_q index decode
 MASK = "mask"
 QUANTIZE = "quantize"

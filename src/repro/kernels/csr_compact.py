@@ -27,8 +27,9 @@ nnz output was designed for):
    Each block writes its window to its own 512 lanes of a (K, nblk * 512)
    output — lane-aligned, no dynamic offsets;
 3. placement in XLA: slot s of a row lives in the block whose inclusive
-   count first exceeds s (a binary search over the exclusive scan of the
-   step-1 counts), at lane ``s - offset[block]`` of that block's window.
+   count first exceeds s (``ref.slot_buckets`` over the inclusive scan of
+   the step-1 counts: one mark per block, one running sum over the slots),
+   at lane ``s - offset[block]`` of that block's window.
 
 Capacity/overflow contract: ``cap`` is the static per-row payload capacity.
 Elements with global rank >= cap fall off the end of the buffer — the
@@ -46,6 +47,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.ref import slot_buckets
 
 BLK = 512
 
@@ -113,8 +116,7 @@ def csr_compact2d_pallas(x, thresholds, cap, *, interpret=True):
     incl = jnp.cumsum(blocks, axis=1)
     nnz = incl[:, -1]
     slots = jnp.arange(cap, dtype=jnp.int32)
-    blk = jax.vmap(lambda c: jnp.searchsorted(c, slots, side="right"))(incl)
-    blk = jnp.minimum(blk, nblk - 1)
+    blk = jnp.minimum(slot_buckets(incl, cap), nblk - 1)
     first = jnp.take_along_axis(incl - blocks, blk, axis=1)
     src = jnp.clip(blk * BLK + slots[None, :] - first, 0, nblk * BLK - 1)
     valid = slots[None, :] < jnp.minimum(nnz, cap)[:, None]

@@ -130,10 +130,24 @@ def _scope_paths(text):
             for op in re.findall(r'op_name="([^"]*)"', text)}
 
 
-def _searches_outside_a_search_scope(text):
+PLACEMENT = "jit(slot_buckets)"
+
+
+def _ops_outside_a_search_scope(text, name):
+    """op_names of compiled HLO text that pass through ``name`` (a
+    primitive or a jitted helper) but through no ``compact`` or ``unpack``
+    scope."""
     return [op for op in re.findall(r'op_name="([^"]*)"', text)
-            if "searchsorted" in op
+            if name in op
             and not {tm.COMPACT, tm.UNPACK} & set(op.split("/")[:-1])]
+
+
+def _placement_ops(text):
+    """Primitives the wire's slot placement (``ref.slot_buckets``) left in
+    compiled HLO text."""
+    return {op.split("/")[-1]
+            for op in re.findall(r'op_name="([^"]*)"', text)
+            if PLACEMENT in op.split("/")[:-1]}
 
 
 @pytest.mark.parametrize("use_kernels", [False, True],
@@ -173,8 +187,12 @@ def test_compiled_round_programs_name_their_stages(data, use_kernels):
             "finalize/distribute/compact"} <= finalize
     assert tm.CLIENT_EPOCH in _scope_paths(texts["epoch"])
     for name in ("upload", "finalize"):
-        assert "searchsorted" in texts[name]
-        assert _searches_outside_a_search_scope(texts[name]) == []
+        # the placement's marks (a scatter-add) survive compilation; every
+        # op of the placement, and the index pack's search, sits under a
+        # compact or unpack scope
+        assert "scatter-add" in _placement_ops(texts[name])
+        assert _ops_outside_a_search_scope(texts[name], PLACEMENT) == []
+        assert _ops_outside_a_search_scope(texts[name], "searchsorted") == []
 
 
 def _checkpoint_sections(tr):
