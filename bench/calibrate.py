@@ -43,7 +43,9 @@ def main(argv=None):
     ap.add_argument("--seeds", type=seeds, default=[])
     ap.add_argument("--control-seeds", type=seeds, default=[])
     ap.add_argument("--fault-seeds", type=seeds, default=[])
-    ap.add_argument("--faults", default=",".join(faults.FAULTS))
+    ap.add_argument("--faults", default=None,
+                    help="comma-separated; default every fault the cell "
+                         "can have (no_exchange on several chips only)")
     ap.add_argument("--leaves", action="store_true",
                     help="also print each leaf's gap behind the readings")
     args = ap.parse_args(argv)
@@ -85,7 +87,10 @@ def main(argv=None):
         ref = reference.run(config, data, seed)
         low = reference.run(config, data, seed, dtype=jnp.bfloat16)
         emit("control", seed, compared(correct.as_program(low, sizes), ref))
-    for kind in [k for k in args.faults.split(",") if k]:
+    kinds = [k for k in args.faults.split(",") if k] if args.faults \
+        else [k for k in faults.FAULTS
+              if cell["chips"] > 1 or k != "no_exchange"]
+    for kind in kinds:
         for seed in args.fault_seeds:
             with faults.planted(kind):
                 values = readings(seed)

@@ -19,7 +19,8 @@ is left out. The numbers:
   (initial weights to g_0);
 * ``client_epoch``: the median over round 1's participants, and
   ``client_epoch_worst`` the worst, of the whole-model change each one's
-  epoch made (before the wire);
+  epoch made (before the wire); not read where the round body returns no
+  trained stack of its own (``program.watch_clients``);
 * ``upload``: the median over round 1's participants of the norm of each
   upload as the server decodes it (values, indices, quantised blocks and
   scales);
@@ -93,33 +94,35 @@ def _flat(prog, sizes):
 
 def readings(prog, ref, sizes):
     """prog: {"global": [g_0..g_3 as {leaf: array}], "ring": [R_1..R_3
-    flat], "clients": {"part", "delta": [flat], "upload_norm"}};
-    ref: ``reference.run`` output -> {number: reading}."""
+    flat], "clients": {"part", "delta": [flat] (may be absent),
+    "upload_norm"}}; ref: ``reference.run`` output -> {number: reading}."""
     init = np.asarray(ref["init"], np.float64)
     pflat = _flat(prog, sizes)
     rflat = [np.asarray(g, np.float64) for g in ref["global"]]
     pc, rc = prog["clients"], ref["clients"]
     if list(pc["part"]) == list(rc["part"]):
-        clients = [whole_gap(p, r) for p, r in zip(pc["delta"], rc["delta"])]
+        clients = [whole_gap(p, r) for p, r in
+                   zip(pc.get("delta", ()), rc["delta"])]
         uploads = [_ratio(abs(p - r), r) for p, r in
                    zip(pc["upload_norm"], rc["upload_norm"])]
     else:                     # another schedule: nothing to compare
         clients = uploads = [float("inf")]
     first = leaf_gaps(leaf_split(pflat[1] - pflat[0], sizes),
                       leaf_split(rflat[1] - rflat[0], sizes))
-    return {"warmup": whole_gap(pflat[0] - init, rflat[0] - init),
-            "client_epoch": float(np.median(clients)),
-            "client_epoch_worst": max(clients),
-            "upload": float(np.median(uploads)),
-            "first_round": max(first.values()),
-            "model": whole_gap(pflat[1] - pflat[0], rflat[1] - rflat[0]),
-            "ring": whole_gap(np.asarray(prog["ring"][0], np.float64)
-                              - pflat[0],
-                              np.asarray(ref["ring"][0], np.float64)
-                              - rflat[0]),
-            "rounds": max(whole_gap(pflat[r] - pflat[r - 1],
-                                    rflat[r] - rflat[r - 1])
-                          for r in range(1, len(rflat)))}
+    out = {"warmup": whole_gap(pflat[0] - init, rflat[0] - init),
+           "client_epoch": float(np.median(clients)) if clients else None,
+           "client_epoch_worst": max(clients) if clients else None,
+           "upload": float(np.median(uploads)),
+           "first_round": max(first.values()),
+           "model": whole_gap(pflat[1] - pflat[0], rflat[1] - rflat[0]),
+           "ring": whole_gap(np.asarray(prog["ring"][0], np.float64)
+                             - pflat[0],
+                             np.asarray(ref["ring"][0], np.float64)
+                             - rflat[0]),
+           "rounds": max(whole_gap(pflat[r] - pflat[r - 1],
+                                   rflat[r] - rflat[r - 1])
+                         for r in range(1, len(rflat)))}
+    return {k: v for k, v in out.items() if v is not None}
 
 
 def leaf_readings(prog, ref, sizes):
@@ -135,7 +138,7 @@ def leaf_readings(prog, ref, sizes):
     return {"warmup": gaps(pflat[0] - init, rflat[0] - init),
             "first_round": gaps(pflat[1] - pflat[0], rflat[1] - rflat[0]),
             "clients": [gaps(p, r) for p, r in
-                        zip(prog["clients"]["delta"],
+                        zip(prog["clients"].get("delta", ()),
                             ref["clients"]["delta"])]}
 
 

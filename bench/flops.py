@@ -2,41 +2,34 @@
 
 The counts describe the algorithm, not an implementation: a later change
 to the program moves the time a round takes, never these numbers. The
-model is the paper's CNN (§V-B): two SAME-padded 1-D convolutions over the
-78 features, a dense hidden layer and a dense classifier. A multiply-add
-counts as two FLOPs. Dropout, the L1 term and Adam's elementwise update
-are not counted, as model-FLOP utilisation leaves them out.
+model's own counts per sample are its plug-in's (``models/<arch>.py``);
+this file adds up a round. A multiply-add counts as two FLOPs. Dropout,
+the L1 term and Adam's elementwise update are not counted, as model-FLOP
+utilisation leaves them out.
 """
 from __future__ import annotations
 
 import math
 
+import plugins
+
 # bytes of one stored element on each wire format: value + column index
 ELEM_BYTES = {"csr": 4 + 4, "csr_q": 1 + 2}
 
 
-def cnn_shapes(model):
-    """(name, fan_in, fan_out, positions) of each weight matrix."""
-    f1, f2 = model["conv_filters"]
-    k, n = model["conv_kernel"], model["num_features"]
-    return [("conv1", k * 1, f1, n), ("conv2", k * f1, f2, n),
-            ("dense", n * f2, model["hidden"], 1),
-            ("out", model["hidden"], model["num_classes"], 1)]
-
-
 def param_count(model):
-    """Weights and biases of the CNN."""
-    return sum(fi * fo + fo for _, fi, fo, _ in cnn_shapes(model))
+    """Parameters of the model (its plug-in, ``models/<arch>.py``)."""
+    return plugins.model(model).param_count(model)
 
 
 def forward_flops(model):
     """FLOPs of one sample's forward pass."""
-    return sum(2 * fi * fo * pos for _, fi, fo, pos in cnn_shapes(model))
+    return plugins.model(model).forward_flops(model)
 
 
 def train_flops(model):
-    """FLOPs of one sample's forward and backward pass (3 x forward)."""
-    return 3 * forward_flops(model)
+    """FLOPs of one sample's forward and backward pass."""
+    return plugins.model(model).train_flops(model)
 
 
 def round_model_flops(model, client_samples, server_samples, epochs=1):
@@ -45,7 +38,7 @@ def round_model_flops(model, client_samples, server_samples, epochs=1):
     samples) and the server's supervised epoch over its labeled split."""
     n = int(sum(client_samples))
     return (epochs * n + server_samples) * train_flops(model) + \
-        n * forward_flops(model)
+        n * plugins.model(model).histogram_flops(model)
 
 
 def upload_work(model, client_samples, n_params, stored, wire_format,
@@ -57,7 +50,8 @@ def upload_work(model, client_samples, n_params, stored, wire_format,
     written once at the wire format's bytes per stored element, and, with
     error feedback, the (K, N) residual read and written once."""
     k = len(client_samples)
-    flops = int(sum(client_samples)) * forward_flops(model)
+    flops = int(sum(client_samples)) * \
+        plugins.model(model).histogram_flops(model)
     nbytes = 2 * k * n_params * 4 + int(stored) * ELEM_BYTES[wire_format]
     if error_feedback:
         nbytes += 2 * k * n_params * 4

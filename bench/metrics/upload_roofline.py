@@ -1,8 +1,9 @@
 """The upload stage's least time over its device time. The least time is
-the larger of the histogram forward FLOPs over the peak FLOP/s and the
-stage's necessary bytes over the HBM bandwidth (``flops.upload_work``:
-the (K, N) trained and base stacks read once, the payload written once
-at the wire's bytes per element, the EF residual read and written once).
+the larger of the histogram forward FLOPs over the chips' peak FLOP/s
+and the stage's necessary bytes over the chips' HBM bandwidth
+(``flops.upload_work``: the (K, N) trained and base stacks read once, the
+payload written once at the wire's bytes per element, the EF residual
+read and written once). The device time is averaged over the chips.
 At the paper CNN the FLOP bound is the larger."""
 UNIT = "%"
 LAYER = "upload (core/sparse_comm.py encode)"
@@ -14,7 +15,7 @@ def read(ctx):
     s = ctx["trace"]["layers"].get("upload")
     if not s:
         return None
-    w, peak = ctx["work"], ctx["peak"]
-    least = max(w["upload_flops"] / peak["flops_per_s"],
-                w["upload_bytes"] / peak["hbm_bytes_per_s"])
+    w, peak, chips = ctx["work"], ctx["peak"], ctx["chips"]
+    least = max(w["upload_flops"] / (chips * peak["flops_per_s"]),
+                w["upload_bytes"] / (chips * peak["hbm_bytes_per_s"]))
     return 100.0 * least / (s / ctx["rounds"])

@@ -1,12 +1,13 @@
 """The system under test, driven the way a user drives it.
 
-Builds the benchmark's data from the seed with the benchmark's own
-generator (``cicids.py``), builds ``repro.core.FedS3ATrainer`` from the
-configuration file and runs ``run_round`` back to back. Nothing else of the
-program is called, apart from reading the trainer's state that the
-comparison needs once the window has closed, and, in round 1 of the
-set-up only, keeping what the batched client epoch and the upload encode
-return on their way through ``run_round`` (``watch_clients``).
+Builds the benchmark's data from the seed with the traffic mix's generator
+plug-in (``generators/``), builds ``repro.core.FedS3ATrainer`` from the
+configuration file, with the model its plug-in (``models/``) names, and
+runs ``run_round`` back to back. Nothing else of the program is called,
+apart from reading the trainer's state that the comparison needs once the
+window has closed, and, in round 1 of the set-up only, keeping what the
+client epoch and the upload encode return on their way through
+``run_round`` (``watch_clients``).
 """
 from __future__ import annotations
 
@@ -16,27 +17,15 @@ from pathlib import Path
 
 import numpy as np
 
-import cicids
+import plugins
 
 ROOT = Path(__file__).resolve().parent.parent
 
 
 def make_data(config, traffic, seed):
     """The fleet's data from the seed: clients, labeled server split, test."""
+    data = plugins.generator(traffic).make(config, traffic, seed)
     fleet = config["fleet"]
-    common = dict(server_frac=fleet["server_frac"],
-                  test_frac=traffic["test_frac"], seed=seed,
-                  separation=traffic["separation"])
-    if traffic["generator"] == "table3":
-        data = cicids.make_dataset(fleet["scenario"], scale=traffic["scale"],
-                                   **common)
-    elif traffic["generator"] == "tiled":
-        data = cicids.make_fleet_dataset(
-            fleet["clients"], scenario=fleet["scenario"],
-            scale=traffic["scale"], jitter=traffic["jitter"],
-            size_seed=traffic["size_seed"], **common)
-    else:
-        raise ValueError(f"unknown generator {traffic['generator']!r}")
     if len(data["clients"]) != fleet["clients"]:
         raise ValueError(f"traffic made {len(data['clients'])} clients, the "
                          f"configuration has {fleet['clients']}")
@@ -53,17 +42,48 @@ def make_trainer(config, engine, data, seed):
     """``FedS3ATrainer`` as the configuration states it (its constructor
     initialises the weights from the seed and runs the server warm-up)."""
     ensure_src()
-    from repro.configs.feds3a_cnn import CNNConfig
     from repro.core import FedS3AConfig, FedS3ATrainer
-    m = config["model"]
-    cnn = CNNConfig(num_features=m["num_features"],
-                    num_classes=m["num_classes"],
-                    conv_filters=tuple(m["conv_filters"]),
-                    conv_kernel=m["conv_kernel"], hidden=m["hidden"],
-                    dropout=m["dropout"])
-    cfg = FedS3AConfig(cnn=cnn, engine=engine, seed=seed,
+    model = plugins.model(config["model"]).program_config(config["model"])
+    cfg = FedS3AConfig(**model, engine=engine, seed=seed,
                        **config["trainer"])
     return FedS3ATrainer(data, cfg)
+
+
+WARM_ROUNDS = 400     # rounds of the schedule whose shapes are warmed up
+
+
+def warm_up(tr):
+    """Compile, in set-up, the programs whose shapes a round picks from the
+    schedule. Under error feedback the chunked and sharded round bodies
+    zero the residual rows of the clients a round retires (forced
+    restarts) with a scatter whose shape is the count of those clients, so
+    each new count compiles anew and the window must compile nothing. The
+    counts are those a copy of the trainer's own scheduler retires over the
+    next ``WARM_ROUNDS`` rounds (the schedule does not depend on what the
+    rounds compute), each compiled once. Call it after a round, so that the
+    residual store has the placement the window's rounds hand the scatter.
+    The scatters run on copies of the store (they donate their input), and
+    the trainer gets its own arrays back. Returns the counts compiled."""
+    reset = getattr(tr, "_reset_forced_residuals", None)
+    if reset is None or not tr.cfg.error_feedback or tr.paged or not (
+            tr.chunked or tr.engine == "sharded"):
+        return []
+    import copy
+
+    import jax.numpy as jnp
+    schedule = copy.deepcopy(tr.scheduler)
+    counts = {len(tr._retired_ids(schedule.next_round()))
+              for _ in range(WARM_ROUNDS)} - {0}
+    store = {k: getattr(tr, k) for k in ("_res_vals", "_res_idx",
+                                         "_residual_mat")
+             if getattr(tr, k, None) is not None}
+    for key, value in store.items():
+        setattr(tr, key, jnp.copy(value))
+    for n in sorted(counts):
+        reset(list(range(n)))
+    for key, value in store.items():
+        setattr(tr, key, value)
+    return sorted(counts)
 
 
 def snapshot(tr):
@@ -73,16 +93,61 @@ def snapshot(tr):
     return dict(tr.global_params), tr.store.latest()
 
 
+def _upload_norms(payload, arity):
+    """Norm of each row's upload as the server decodes it, from a wire
+    payload tuple of ``arity`` components per chunk (one chunk on the flat
+    paths): ``csr`` carries the values, ``csr_q`` the int8 values and the
+    message's scale last."""
+    norms = []
+    for c in range(0, len(payload), arity):
+        vals = np.asarray(payload[c], np.float64)
+        n = np.linalg.norm(vals.reshape(vals.shape[0], -1), axis=1)
+        if arity == 4:
+            n = n * np.asarray(payload[c + 3], np.float64).reshape(-1)
+        norms.append(n)
+    total = norms[0] if len(norms) == 1 else \
+        np.sqrt(np.sum(np.square(norms), axis=0))
+    return [float(x) for x in total]
+
+
 @contextlib.contextmanager
 def watch_clients(tr):
-    """While the context is open, copy to the host the change each
-    participant's epoch made (the batched client epoch's trained stack less
-    its base stack) and the norm of each upload as the server will decode
-    it (``csr``: the values; ``csr_q``: the int8 values times the message's
-    scale), as the trainer's own calls return them. The copies are made at
-    once, so no device buffer outlives its use in the round; the trainer is
-    left as it was on exit."""
+    """While the context is open, copy to the host what the round's own
+    calls return: the norm of each upload as the server will decode it and,
+    where the client epoch returns its trained stack on its own, the change
+    each participant's epoch made (trained stack less base stack). One
+    watch per round body the trainer has:
+
+    * batched: ``batched_epoch`` and ``_upload_fn``;
+    * chunked: ``batched_epoch`` and ``_chunk_upload_fn``;
+    * sharded: ``_stage1_sharded``, which trains and encodes in one
+      program and returns no trained stack, so no epoch change is taken
+      (nor under a sharded chunked round, whose epoch gathers its base
+      inside the program).
+
+    Rows past the round's participants (the sharded body's padding) are
+    copied too; the caller keeps the first K. The copies are made at once,
+    so no device buffer outlives its use in the round; the trainer is left
+    as it was on exit."""
     seen = {}
+    arity = tr._payload_arity
+    cls = type(tr)
+    sharded = tr.engine == "sharded"
+    name = "_chunk_upload_fn" if tr.chunked else \
+        "_stage1_sharded" if sharded else "_upload_fn"
+
+    def stage(*args):
+        fn = getattr(cls, name)(tr, *args)
+
+        def watched(*inputs):
+            out = fn(*inputs)
+            # the sharded stage returns the payload's components first;
+            # the upload programs return the payload tuple first
+            payload = out[:arity] if name == "_stage1_sharded" else out[0]
+            seen["upload_norm"] = _upload_norms(payload, arity)
+            return out
+        return watched
+
     epoch = tr.batched_epoch
 
     def batched_epoch(base, *args):
@@ -91,23 +156,11 @@ def watch_clients(tr):
             np.asarray(base, np.float32)
         return out
 
-    def upload_fn(*args):
-        fn = type(tr)._upload_fn(tr, *args)
-
-        def encode(*inputs):
-            out = fn(*inputs)
-            payload = out[0]
-            vals = np.asarray(payload[0], np.float64)
-            norms = np.linalg.norm(vals.reshape(vals.shape[0], -1), axis=1)
-            if len(payload) == 4:
-                norms = norms * np.asarray(payload[3], np.float64)
-            seen["upload_norm"] = [float(x) for x in norms]
-            return out
-        return encode
-
-    tr.batched_epoch, tr._upload_fn = batched_epoch, upload_fn
+    setattr(tr, name, stage)
+    if not sharded:
+        tr.batched_epoch = batched_epoch
     try:
         yield seen
     finally:
         tr.batched_epoch = epoch
-        del tr._upload_fn
+        delattr(tr, name)

@@ -6,8 +6,10 @@ initial weights, server warm-up, scheduler, client epochs, sparse (and
 quantised) uploads with error feedback, pseudo-label histograms, k-means
 grouping, staleness- and group-weighted aggregation, the server's
 supervised epoch and the chain-delta distribution ring. Clients train one
-at a time, in plain ``jax.numpy`` over parameter dicts, with convolutions
-as ``lax.conv`` and every matmul at ``highest`` precision.
+at a time, in plain ``jax.numpy`` over parameter dicts, with every matmul
+at ``highest`` precision. The model itself (initial weights, forward pass,
+leaf order) is its plug-in's (``models/<arch>.py``); this file holds the
+round's arithmetic.
 
 ``dtype=bfloat16`` runs the same arithmetic with parameters, optimizer
 state and activations in bfloat16 at default matmul precision: the control
@@ -21,9 +23,11 @@ strided sample of the flat delta (leaf order: sorted parameter names),
 capped at ``ceil(2.5 f N)`` survivors in column order, ``csr_q`` rounds the
 kept values to int8 against the per-message absmax / 127, error feedback
 carries ``delta - decoded`` truncated the same way to the top
-``residual_frac``, Eq. 9-10 group weights, the adaptive supervised weight
-and learning rates (Eq. 11-12), and the paper's latency model for the
-semi-asynchronous schedule (§V-D3).
+``residual_frac`` (with ``chunk_size``, each leaf-aligned chunk of the
+flat vector is a message of its own: ``chunk_bounds``), Eq. 9-10 group
+weights, the adaptive supervised weight and learning rates (Eq. 11-12),
+and the paper's latency model for the semi-asynchronous schedule
+(§V-D3).
 """
 from __future__ import annotations
 
@@ -35,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import plugins
+
 ADAM = dict(b1=0.9, b2=0.999, eps=1e-8)
 CAP_FACTOR = 2.5
 QUANTILE_SAMPLE = 2048
@@ -43,44 +49,18 @@ TABLE3_TOTAL = 453004                # the latency model's unscaled total
 
 
 # -- model ------------------------------------------------------------------
+# the model's own pieces (weights, forward pass, leaf order) are its
+# plug-in's, ``models/<arch>.py``, chosen by ``model["arch"]``
 def init_params(model, key):
-    """He-normal weights, zero biases, from one key split five ways."""
-    ks = jax.random.split(key, 5)
-    f1, f2 = model["conv_filters"]
-    k, n, h, c = (model["conv_kernel"], model["num_features"],
-                  model["hidden"], model["num_classes"])
-
-    def he(key, shape, fan_in):
-        return jax.random.normal(key, shape) * math.sqrt(2.0 / fan_in)
-
-    return {"conv1_w": he(ks[0], (k, 1, f1), k),
-            "conv1_b": jnp.zeros((f1,)),
-            "conv2_w": he(ks[1], (k, f1, f2), k * f1),
-            "conv2_b": jnp.zeros((f2,)),
-            "dense_w": he(ks[2], (n * f2, h), n * f2),
-            "dense_b": jnp.zeros((h,)),
-            "out_w": he(ks[3], (h, c), h),
-            "out_b": jnp.zeros((c,))}
-
-
-def _conv(x, w, b):
-    pad = (w.shape[0] - 1) // 2
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(1,), padding=[(pad, w.shape[0] - 1 - pad)],
-        dimension_numbers=("NWC", "WIO", "NWC"))
-    return y + b
+    return plugins.model(model).init_params(model, key)
 
 
 def forward(model, p, x, drop_key=None):
-    """(B, features) -> logits (B, classes); dropout after the hidden
-    layer when ``drop_key`` is given."""
-    h = jax.nn.relu(_conv(x[..., None], p["conv1_w"], p["conv1_b"]))
-    h = jax.nn.relu(_conv(h, p["conv2_w"], p["conv2_b"]))
-    h = jax.nn.relu(h.reshape(h.shape[0], -1) @ p["dense_w"] + p["dense_b"])
-    if drop_key is not None and model["dropout"] > 0:
-        keep = 1.0 - model["dropout"]
-        h = h * jax.random.bernoulli(drop_key, keep, h.shape) / keep
-    return h @ p["out_w"] + p["out_b"]
+    return plugins.model(model).forward(model, p, x, drop_key)
+
+
+def leaf_sizes(model):
+    return plugins.model(model).leaf_sizes(model)
 
 
 def flatten(p):
@@ -95,12 +75,6 @@ def unflatten(flat, like):
         out[k] = flat[i:i + n].reshape(like[k].shape).astype(like[k].dtype)
         i += n
     return out
-
-
-def leaf_sizes(model):
-    shapes = jax.eval_shape(lambda k: init_params(model, k),
-                            jax.random.PRNGKey(0))
-    return [(k, int(np.prod(shapes[k].shape))) for k in sorted(shapes)]
 
 
 # -- optimisation -------------------------------------------------------------
@@ -200,18 +174,59 @@ def quantize_int8(d):
     return (jnp.clip(jnp.round(d * inv), -127, 127) * scale).astype(d.dtype)
 
 
-@partial(jax.jit, static_argnames=("keep", "wire", "res_frac"))
-def encode(delta, *, keep, wire, res_frac):
+def chunk_bounds(sizes, chunk_size):
+    """The wire's chunks of the flat vector under ``chunk_size``, as
+    ((start, end), ...), or None for one chunk: consecutive leaves (``sizes``
+    in the flat order) are packed greedily into chunks of at most
+    ``chunk_size`` values, and a larger leaf is split into pieces of
+    ``chunk_size`` with a ragged last one."""
+    if not chunk_size:
+        return None
+    bounds, cur, off = [], None, 0
+    for _, n in sizes:
+        if n > chunk_size:
+            if cur:
+                bounds.append(cur)
+                cur = None
+            bounds += [(s, min(s + chunk_size, off + n))
+                       for s in range(off, off + n, chunk_size)]
+        elif cur and cur[1] - cur[0] + n <= chunk_size:
+            cur = (cur[0], cur[1] + n)
+        else:
+            if cur:
+                bounds.append(cur)
+            cur = (off, off + n)
+        off += n
+    if cur:
+        bounds.append(cur)
+    return None if len(bounds) == 1 else tuple(bounds)
+
+
+@partial(jax.jit, static_argnames=("keep", "wire", "res_frac", "chunks"))
+def encode(delta, *, keep, wire, res_frac, chunks=None):
     """-> (decoded, stored, residual): what the receiver rebuilds, the
-    stored count and, with ``res_frac``, the truncated EF residual."""
+    stored count and, with ``res_frac``, the truncated EF residual. With
+    ``chunks`` each chunk is a message of its own: its own quantile,
+    capacity, int8 scale and residual."""
+    parts = [_encode_one(delta[s:e], keep, wire, res_frac)
+             for s, e in chunks or ((0, delta.shape[0]),)]
+    if len(parts) == 1:
+        return parts[0]
+    decoded, stored, residual = zip(*parts)
+    return (jnp.concatenate(decoded), sum(stored),
+            jnp.concatenate(residual) if res_frac else None)
+
+
+def _encode_one(delta, keep, wire, res_frac):
     n = delta.shape[0]
-    decoded, stored = sparsify(delta, keep, math.ceil(CAP_FACTOR * keep * n))
+    decoded, stored = sparsify(
+        delta, keep, max(1, min(n, math.ceil(CAP_FACTOR * keep * n))))
     if wire == "csr_q":
         decoded = quantize_int8(decoded)
     residual = None
     if res_frac:
         residual, _ = sparsify(delta - decoded, res_frac,
-                               math.ceil(res_frac * n))
+                               max(1, min(n, math.ceil(res_frac * n))))
     return decoded, stored, residual
 
 
@@ -308,7 +323,7 @@ def learning_rates(participation, M, lr):
 FOLLOWS = {"epochs": 1, "server_epochs": 1, "staleness_function": "exponential",
            "round_weight_function": "exponential", "adaptive_lr": True,
            "supervised_weight_mode": "adaptive", "sparse_comm": True,
-           "q_dtype": "int8", "base_store": "versioned", "chunk_size": 0}
+           "q_dtype": "int8", "base_store": "versioned"}
 
 
 def _padded(x, rows):
@@ -339,7 +354,9 @@ def run(config, data, seed, rounds=3, *, dtype=jnp.float32):
     cast = partial(jax.tree.map, lambda a: a.astype(dtype))
     epoch = partial(_epoch, model=mkey, batch=B, l1=l1)
     hist = partial(_histogram, model=mkey, batch=B)
-    enc = partial(encode, keep=keep, wire=wire, res_frac=res_frac)
+    chunks = chunk_bounds(leaf_sizes(model), tc["chunk_size"])
+    enc = partial(encode, keep=keep, wire=wire, res_frac=res_frac,
+                  chunks=chunks)
 
     clients = [c["x"] for c in data["clients"]]
     M, sizes = len(clients), [len(x) for x in clients]
@@ -421,7 +438,7 @@ def run(config, data, seed, rounds=3, *, dtype=jnp.float32):
             new = fw * flatten(srv) + (1.0 - fw) * agg
             prev = ring[r]
             decoded, _, _ = encode(new.astype(dtype) - prev, keep=keep,
-                                   wire=wire, res_frac=0.0)
+                                   wire=wire, res_frac=0.0, chunks=chunks)
             ring[r + 1] = prev + decoded
             version[sorted(set(part) | set(forced))] = r + 1
             for i in forced:

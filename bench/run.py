@@ -5,7 +5,10 @@
 
 A cell (``bench/cells/<name>.json``) names a configuration
 (``bench/configs/``), a traffic mix (``bench/traffic/``), the chips it needs,
-the round engine and the limits of its correctness check.
+the round engine, the limits of its correctness check and, optionally, how
+its trace is attributed to layers (``"attribution": "scopes"``; by module
+name otherwise). The configuration's model and the mix's generator are
+plug-ins found by name (``plugins.py``, ``models/``, ``generators/``).
 
 Set-up makes the data from the seed, builds ``FedS3ATrainer`` (weights
 from the seed and the server warm-up) and drives it through its first
@@ -35,7 +38,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -54,8 +56,10 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import correct  # noqa: E402
 import flops  # noqa: E402
 import peaks  # noqa: E402
+import plugins  # noqa: E402
 import program  # noqa: E402
 import reference  # noqa: E402
+import scopes  # noqa: E402
 import trace_reduce  # noqa: E402
 
 SETUP_ROUNDS = 3          # the rounds the reference follows
@@ -85,12 +89,7 @@ def manifest(root=REPO):
 def metric_reader(name, root=HERE):
     """The module ``metrics/<name>.py``: ``read(ctx)`` and its UNIT,
     LAYER, MOVES and SOURCE."""
-    path = Path(root) / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return plugins.module("metrics", name, root)
 
 
 def cell_metrics(bench, cell_name, section):
@@ -190,8 +189,9 @@ def set_up(config, cell, traffic, seed, counter,
     where ``first`` holds the model after the warm-up and after each of the
     rounds the reference follows (device buffers held by reference) and,
     as host arrays, what round 1's client epochs and uploads produced
-    (``program.watch_clients``).
-    Then up to ``extra_rounds`` more rounds, until one compiles nothing."""
+    (``program.watch_clients``). Then the warm-up of the shapes the
+    schedule picks (``program.warm_up``), and up to ``extra_rounds`` more
+    rounds, until one compiles nothing."""
     import jax
     data = program.make_data(config, traffic, seed)
     tr = program.make_trainer(config, cell["engine"], data, seed)
@@ -200,8 +200,10 @@ def set_up(config, cell, traffic, seed, counter,
         if r == 0:
             with program.watch_clients(tr) as seen:
                 tr.run_round()
-            first["clients"] = dict(
-                seen, part=[int(i) for i in tr.logs[-1].participants])
+            part = [int(i) for i in tr.logs[-1].participants]
+            first["clients"] = {key: v[:len(part)] for key, v in
+                                seen.items()}
+            first["clients"]["part"] = part
         else:
             tr.run_round()
         g, ring = program.snapshot(tr)
@@ -209,6 +211,7 @@ def set_up(config, cell, traffic, seed, counter,
         tr.comm.payload_bytes
         first["global"].append(g)
         first["ring"].append(ring)
+    program.warm_up(tr)
     for _ in range(extra_rounds):
         counter.start()
         one_round(tr)
@@ -333,7 +336,11 @@ def traced_metrics(bench, cell_name, cell, config, data, logs, trace_dir,
     window = (min(s for s, _ in events["steps"]),
               max(e for _, e in events["steps"]))
     red = trace_reduce.reduce(events, window)
-    check_launches(red["nth_launches"], rounds)
+    if cell.get("attribution", "modules") == "scopes":
+        red.update(trace_reduce.scope_layers(scopes.read_once(files[-1]),
+                                             red["busy_s"]))
+    else:
+        check_launches(red["nth_launches"], rounds)
     ctx = {"trace": red, "rounds": rounds, "round_s": round_s,
            "chips": cell["chips"], "peak": peaks.peaks(devs[0].device_kind),
            "work": round_work(config, data, logs), "config": config,
@@ -348,7 +355,9 @@ def traced_metrics(bench, cell_name, cell, config, data, logs, trace_dir,
             {"modules_s": sorted(red["modules"].items(),
                                  key=lambda kv: -kv[1]),
              "layers_s": red["layers"], "loop_steps": red["loop_steps"],
-             "longest_gaps": red["longest_gaps"]})
+             "longest_gaps": red["longest_gaps"],
+             **({"other_share": red["other_share"]}
+                if "other_share" in red else {})})
 
 
 def main(argv=None):

@@ -17,6 +17,13 @@ operation when it is one of the parts of that path before the primitive.
   averaged over devices;
 * ``search_s``: the same union for every operation held by a ``compact``
   or ``unpack`` scope, wherever it sits (the wire's slot searches);
+* ``unscoped_s``: the time in which only operations held by no scope ran;
+* ``collective_s``: the union of the collectives' intervals (all-reduce,
+  all-gather, reduce-scatter, all-to-all, collective-permute, and their
+  async start and done, by the operation's HLO name);
+* loop steps per outermost scope: in each timed round, the most runs of
+  any one operation the scope holds (the trip count of its main loop: a
+  loop body's operations run once per step), summed over the rounds;
 * the program's spans (``TraceAnnotation`` events on a host plane whose
   name is a span of the program, with no ``step_num``): host seconds per
   name inside the rounds;
@@ -33,6 +40,7 @@ trace is the newest ``*.xplane.pb`` under ``bench_out/``, so
 """
 from __future__ import annotations
 
+import bisect
 import functools
 import re
 import sys
@@ -43,6 +51,8 @@ HERE = Path(__file__).resolve().parent
 BENCH_OUT = HERE.parent / "bench_out"
 DEVICE = re.compile(r"^/device:([A-Z]+):(\d+)$")
 OP_NAME = "tf_op"        # stat of an XLA op: "<op_name>:<op type>"
+COLLECTIVE = re.compile(r"^%?(all-reduce|all-gather|reduce-scatter|"
+                        r"all-to-all|collective-permute)\b")
 SEARCH = frozenset({"compact", "unpack"})
 TOP_GAPS = 10
 
@@ -139,7 +149,7 @@ def _plane(buf, start, end, spans, out):
     for s, e in _map_entries(buf, stat_md):
         md = dict(_fields(buf, s, e))
         names[md.get(1, 0)] = _text(buf, md[2]) if 2 in md else ""
-    metadata = {}
+    metadata, hlo = {}, {}
     for s, e in _map_entries(buf, event_md):
         md, st = {}, []
         for f, v in _fields(buf, s, e):
@@ -149,10 +159,11 @@ def _plane(buf, start, end, spans, out):
                 md[f] = v
         label = _text(buf, md[2]) if 2 in md else ""
         if device:
+            hlo[md.get(1, 0)] = label
             label = str(_stats(buf, st, names).get(OP_NAME, ""))
         metadata[md.get(1, 0)] = label
-    ops = out["devices"].setdefault(int(device.group(2)), []) \
-        if device else None
+    if device:
+        ops = out["devices"].setdefault(int(device.group(2)), [])
     for ls, le in lines:
         line, events = {}, []
         for f, v in _fields(buf, ls, le):
@@ -174,7 +185,8 @@ def _plane(buf, start, end, spans, out):
             stop = begin + ev.get(3, 0) / 1e3
             label = metadata.get(ev.get(1, 0), "")
             if device:
-                ops.append((label.rsplit(":", 1)[0], begin, stop))
+                ops.append((label.rsplit(":", 1)[0], begin, stop,
+                            hlo.get(ev.get(1, 0), "")))
             elif st and "step_num" in _stats(buf, st, names):
                 out["steps"].append((begin, stop))
             elif label in spans:
@@ -183,11 +195,11 @@ def _plane(buf, start, end, spans, out):
 
 def load(data, spans=()):
     """A serialized XSpace (the ``.xplane.pb`` the profiler writes) ->
-    {"devices": {id: [(op_name, start, end)]}, "spans": [(name, start,
-    end)], "steps": [(start, end)]}, times in ns on the trace's clock.
-    ``spans`` names the host events kept. The op_name sits in the stats
-    of an op's event metadata (``jax.profiler.ProfileData`` does not show
-    those), so the file is read here, field by field."""
+    {"devices": {id: [(op_name, start, end, HLO name)]}, "spans": [(name,
+    start, end)], "steps": [(start, end)]}, times in ns on the trace's
+    clock. ``spans`` names the host events kept. The op_name sits in the
+    stats of an op's event metadata (``jax.profiler.ProfileData`` does not
+    show those), so the file is read here, field by field."""
     out = {"devices": {}, "spans": [], "steps": []}
     buf = memoryview(data)
     for f, v in _fields(buf, 0, len(buf)):
@@ -226,35 +238,52 @@ def scope_path(op_name, scopes):
 
 
 def reduce(events, scopes=(), top=TOP_GAPS):
-    """Events from :func:`load` -> seconds per scope path and of the slot
-    searches (device, per device), host seconds per span name, and the
-    idle gaps by span."""
+    """Events from :func:`load` -> seconds per scope path, of the slot
+    searches, of unscoped time and of the collectives (device, per
+    device), loop steps per outermost scope (per device), host seconds per
+    span name, and the idle gaps by span."""
     if not events["steps"]:
         raise ValueError("the trace holds no timed round (step_num)")
     if not events["devices"]:
         raise ValueError("the trace holds no device plane")
     scopes = frozenset(scopes)
-    steps = _merged(events["steps"])
+    rounds = sorted(events["steps"])
+    starts = [s for s, _ in rounds]
+    steps = _merged(rounds)
     w0, w1 = steps[0][0], steps[-1][1]
     ns = 1e-9
     d = len(events["devices"])
-    per_path, search = defaultdict(float), 0.0
+    per_path, loops = defaultdict(float), defaultdict(float)
+    search = unscoped = collective = 0.0
     for ops in events["devices"].values():
-        held, found = defaultdict(list), []
-        for name, s, e in ops:
+        held, found, scoped, colls = defaultdict(list), [], [], []
+        runs = defaultdict(lambda: defaultdict(int))
+        for name, s, e, op in ops:
             path = scope_path(name, scopes)
             for i in range(1, len(path) + 1):
                 held["/".join(path[:i])].append((s, e))
             if SEARCH.intersection(name.split("/")[:-1]):
                 found.append((s, e))
+            if COLLECTIVE.match(op):
+                colls.append((s, e))
+            if path:
+                scoped.append((s, e))
+                r = bisect.bisect_right(starts, s) - 1
+                if r >= 0 and s < rounds[r][1]:
+                    runs[path[0], r][op] += 1
         for key, iv in held.items():
             per_path[key] += _overlap(_merged(iv), steps)
+        for (scope, _), count in runs.items():
+            loops[scope] += max(count.values())
         search += _overlap(_merged(found), steps)
+        collective += _overlap(_merged(colls), steps)
+        unscoped += _overlap(_merged([(s, e) for _, s, e, _ in ops]),
+                             steps) - _overlap(_merged(scoped), steps)
     span_s = defaultdict(float)
     for name, s, e in events["spans"]:
         span_s[name] += _overlap([[s, e]], steps)
     first = events["devices"][min(events["devices"])]
-    busy = _merged([(max(s, w0), min(e, w1)) for _, s, e in first
+    busy = _merged([(max(s, w0), min(e, w1)) for _, s, e, _ in first
                     if e > w0 and s < w1])
     edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
     gaps, by_span = [], defaultdict(float)
@@ -273,6 +302,9 @@ def reduce(events, scopes=(), top=TOP_GAPS):
         "devices": d,
         "scopes_s": {k: v / d * ns for k, v in sorted(per_path.items())},
         "search_s": search / d * ns,
+        "unscoped_s": unscoped / d * ns,
+        "collective_s": collective / d * ns,
+        "loop_steps": {k: v / d for k, v in sorted(loops.items())},
         "spans_s": {k: v * ns for k, v in sorted(span_s.items())},
         "idle_by_span_s": dict(sorted(by_span.items(),
                                       key=lambda kv: -kv[1])),
@@ -284,6 +316,12 @@ def read(path):
     """Reduce one ``.xplane.pb`` with the program's vocabulary."""
     spans, scopes = vocabulary()
     return reduce(load(Path(path).read_bytes(), spans), scopes)
+
+
+def read_once(path):
+    """:func:`read`, made once per file."""
+    path = Path(path)
+    return _read_cached(str(path), path.stat().st_mtime_ns)
 
 
 def newest_trace(root=BENCH_OUT):
@@ -299,9 +337,7 @@ def _read_cached(path, _mtime_ns):
 def reduction(root=BENCH_OUT):
     """The reduction of the newest trace under ``root``, or None."""
     path = newest_trace(root)
-    if path is None:
-        return None
-    return _read_cached(str(path), path.stat().st_mtime_ns)
+    return None if path is None else read_once(path)
 
 
 def main(argv=None):

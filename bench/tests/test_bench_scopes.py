@@ -46,10 +46,11 @@ def test_load_keeps_the_timed_round_and_the_program_spans(reduced):
 def test_load_reads_the_op_name_from_the_event_metadata(reduced):
     events, _ = reduced
     ops = events["devices"][0]
-    # the stat reads "<op_name>:<op type>"; a while may carry none
-    assert ops[0] == ("", 1e6, 4e6)
+    # the stat reads "<op_name>:<op type>"; a while may carry none; the
+    # event metadata's own name is the op's HLO name
+    assert ops[0] == ("", 1e6, 4e6, "while.1")
     assert ops[3] == ("jit(body)/upload/histogram/jit(run)/dot_general",
-                      4e6, 4.5e6)
+                      4e6, 4.5e6, "fusion.4")
 
 
 def test_a_while_and_the_fusions_inside_it_count_once(reduced):

@@ -20,6 +20,11 @@ event per launched program and the ``XLA Ops`` line one per operation.
   each named by the innermost host Python frame that spans its midpoint;
   the very longest also list the other host threads' events that overlap
   them.
+
+A cell with ``"attribution": "scopes"`` takes its layers and loop steps
+from :func:`scope_layers` instead: each device operation goes to the layer
+of its outermost program scope (``layers.json`` "scopes", reduced by
+``scopes.reduce``), with no rule by module name or launch order.
 """
 from __future__ import annotations
 
@@ -216,3 +221,24 @@ def reduce(events, window, rules=None, top=10):
                             key=lambda kv: -kv[1])[:top],
         "longest_gaps": longest,
     }
+
+
+def scope_layers(by_scope, busy_s):
+    """``scopes.reduce`` of the timed rounds and their busy seconds ->
+    device seconds and loop steps per layer, by ``layers.json`` "scopes":
+    each outermost program scope gives its time and loop steps to its
+    layer. Time under no scope, or under an outermost scope the map does
+    not name, goes to "other"; also its share of the busy seconds, and the
+    collectives' seconds."""
+    layer_of = json.loads(LAYERS.read_text())["scopes"]
+    layers, steps = defaultdict(float), defaultdict(float)
+    layers["other"] = by_scope["unscoped_s"]
+    for path, sec in by_scope["scopes_s"].items():
+        if "/" not in path:
+            layers[layer_of.get(path, "other")] += sec
+    for scope, n in by_scope["loop_steps"].items():
+        if scope in layer_of:
+            steps[layer_of[scope]] += n
+    return {"layers": dict(layers), "loop_steps": dict(steps),
+            "other_share": layers["other"] / busy_s if busy_s else 0.0,
+            "collective_s": by_scope["collective_s"]}
